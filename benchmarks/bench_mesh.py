@@ -27,8 +27,12 @@ bit-identity witness vs the solo regime (``bitidentical`` — gated exactly
 by tools/check_bench.py).
 
 The 8 host devices require ``--xla_force_host_platform_device_count=8``
-BEFORE jax initializes, so the measurement runs in a child interpreter;
-this module just launches it and records the rows.
+BEFORE jax initializes, so the measurement runs in a child interpreter
+pinned to the CPU backend; this module just launches it and records the
+rows, which are CPU-mesh counts (``bench_mesh/platform`` = cpu), never chip
+numbers. A child started after the parent has initialised a JAX backend
+could find the chip held, so :func:`run` refuses then; ``benchmarks/run.py``
+runs this bench first for that reason.
 
     PYTHONPATH=src python benchmarks/bench_mesh.py
 """
@@ -47,7 +51,7 @@ N_DEVICES = 8
 _CHILD = """
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
+os.environ["JAX_PLATFORMS"] = "cpu"
 import json, sys, time
 sys.path.insert(0, "benchmarks")
 sys.path.insert(0, "src")
@@ -128,6 +132,12 @@ print("MESH_ROWS_JSON:" + json.dumps({
 
 
 def run():
+    from jax._src import xla_bridge
+
+    if xla_bridge.backends_are_initialized():
+        raise RuntimeError(
+            "bench_mesh starts a CPU child process; run it before anything in "
+            "this process initialises a JAX backend")
     out = subprocess.run([sys.executable, "-c", _CHILD], capture_output=True,
                          text=True, cwd=ROOT, timeout=1200)
     payload = next((line.split(":", 1)[1] for line in out.stdout.splitlines()
@@ -139,6 +149,7 @@ def run():
     d = json.loads(payload)
     n = d["requests"]
     rows = [
+        ("bench_mesh/platform", 0, "cpu"),
         ("bench_mesh/devices", 0, N_DEVICES),
         ("bench_mesh/shards", 0, d["shards"]),
         ("bench_mesh/requests", 0, n),

@@ -13,7 +13,10 @@ import traceback
 sys.path.insert(0, os.path.dirname(__file__))
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
+# bench_mesh goes first: it starts a CPU child process, which must happen
+# before this process initialises a JAX backend (see bench_mesh.run)
 MODULES = [
+    "bench_mesh",
     "fig3_similarity",
     "fig4_value_range",
     "fig5_bitwidth",
@@ -34,7 +37,6 @@ MODULES = [
     "bench_schedule",
     "bench_latency",
     "bench_faults",
-    "bench_mesh",
 ]
 
 
@@ -76,6 +78,9 @@ def main(argv=None) -> None:
                          "diff-step kernel vs the two-pass path)")
     args = ap.parse_args(argv)
     modules = ["bench_fused_step"] if args.fused else MODULES
+    from repro.compile_cache import use_compile_cache
+
+    use_compile_cache()
 
     failures = []
     for mod_name in modules:

@@ -18,12 +18,15 @@ import jax
 import numpy as np
 
 from repro import configs
+from repro.compile_cache import use_compile_cache
 from repro.checkpoint.manager import CheckpointManager
 from repro.data.synthetic import DataCfg, batch_for
 from repro.launch import steps as steps_mod
+from repro.launch.mesh import make_test_mesh
 
 
 def main():
+    use_compile_cache()
     arch = configs.get("qwen3-0.6b").smoke()
     opt = steps_mod.make_optimizer(arch, total=20)
     dc = DataCfg(seed=0, batch=4, seq_len=32)
@@ -42,7 +45,7 @@ def main():
     # target shardings — the same call accepts any NamedSharding tree
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_test_mesh(1)
     shard_tree = jax.tree.map(lambda _: NamedSharding(mesh, P()), state)
     state2 = mgr.restore(10, state, shardings=shard_tree)
     for a, b in zip(jax.tree.leaves(state), jax.tree.leaves(state2)):

@@ -19,6 +19,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import configs
+from repro.compile_cache import use_compile_cache
 from repro.core import diffusion
 from repro.data.synthetic import DataCfg, batch_for
 from repro.launch import steps as steps_mod
@@ -27,6 +28,7 @@ from repro.sim import harness
 
 
 def main():
+    use_compile_cache()
     # ---- 1. train a small denoiser -------------------------------------
     arch = dataclasses.replace(
         configs.get("dit-xl2").smoke(), n_layers=3, d_model=64, input_size=16, n_classes=8

@@ -78,6 +78,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import configs
+from repro.compile_cache import use_compile_cache
 from repro.core import diffusion
 from repro.data.synthetic import DataCfg, batch_for
 from repro.launch import steps as steps_mod
@@ -211,6 +212,7 @@ def main(argv=None):
                          "and the re-anchor watchdog armed; every request "
                          "must still resolve")
     args = ap.parse_args(argv)
+    use_compile_cache()
     if args.int4_from is not None and not 0 < args.int4_from < args.steps:
         ap.error(f"--int4-from must be inside (0, {args.steps})")
     if args.chaos is not None and args.int4_from is not None:

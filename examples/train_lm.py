@@ -18,11 +18,13 @@ import jax
 import jax.numpy as jnp
 
 from repro import configs
+from repro.compile_cache import use_compile_cache
 from repro.distributed import collectives
 from repro.launch.train import TrainDriver
 
 
 def main():
+    use_compile_cache()
     arch = configs.get("smollm-360m").smoke()
     workdir = tempfile.mkdtemp(prefix="repro_lm_")
 

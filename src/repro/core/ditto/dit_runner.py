@@ -26,7 +26,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ...distributed.sharding import constrain_batch
+from ...distributed.sharding import constrain_batch, require_partitionable
 from ...kernels.common import DEFAULT_LOW_BITS
 from ...nn import core as nncore
 from ...nn import dit as dit_mod
@@ -63,6 +63,18 @@ def _v(tree, *path):
     return np.asarray(nncore.val(cur))
 
 
+def _cond_dense(p, c):
+    """``nncore.dense`` over per-sample conditioning rows ``(B, d)``, with a
+    lone row duplicated. XLA:TPU lowers a one-row f32 matmul as a VPU
+    multiply-reduce (f32 products) but two or more rows on the MXU, and the
+    two round differently: without this a sample served alone would not
+    equal the same sample served in a batch. The barrier keeps the compiler
+    from slicing the duplicate away before the matmul."""
+    if c.shape[0] != 1:
+        return nncore.dense(p, c)
+    return jax.lax.optimization_barrier(nncore.dense(p, jnp.concatenate([c, c])))[:1]
+
+
 def _dit_forward(params, cfg: dit_mod.DiTCfg, linear, attention, latents, t, labels):
     """One DiT forward with every quantized op injected.
 
@@ -76,7 +88,7 @@ def _dit_forward(params, cfg: dit_mod.DiTCfg, linear, attention, latents, t, lab
     x = x.transpose(0, 1, 3, 2, 4, 5).reshape(b, cfg.n_tokens, cfg.patch_dim)
     x = nncore.dense(params["patch_embed"], x) + nncore.val(params["pos_embed"])[None]
     c = dit_mod.timestep_embedding(t, 256)
-    c = nncore.dense(params["t_mlp2"], jax.nn.silu(nncore.dense(params["t_mlp1"], c)))
+    c = _cond_dense(params["t_mlp2"], jax.nn.silu(_cond_dense(params["t_mlp1"], c)))
     if labels is not None and "label_embed" in params:
         c = c + nncore.val(params["label_embed"])[labels]
     c_act = jax.nn.silu(c)
@@ -105,7 +117,7 @@ def _dit_forward(params, cfg: dit_mod.DiTCfg, linear, attention, latents, t, lab
         hmid = jax.nn.gelu(linear(f"{bk}.wi", h))
         x = x + g_m[:, None, :] * linear(f"{bk}.wd", hmid)
 
-    modf = nncore.dense(params["final_mod"], c_act)
+    modf = _cond_dense(params["final_mod"], c_act)
     shift, scl = jnp.split(modf, 2, axis=-1)
     x = dit_mod._modulate(dit_mod._ln(x), shift, scl)
     x = linear("final.out", x)
@@ -190,6 +202,7 @@ def make_step_fn(cfg: dit_mod.DiTCfg, modes: dict[str, str], plan: DittoPlan | N
     # MESH_SIG_FIELDS are cache_sig() fields. mesh_sig=None leaves the
     # jaxpr untouched (bit-for-bit the pre-mesh trace).
     msig = plan.mesh_sig()
+    require_partitionable(plan)
 
     def step(dparams, mparams, state, latents, t, labels):
         latents = constrain_batch(latents, msig)

@@ -66,8 +66,6 @@ def compressed_psum_grads(grads, residuals, mesh: Mesh, axis_names=("data",)):
 
 def make_compressed_allreduce(mesh: Mesh, axis_names=("data",)):
     """shard_map-wrapped compressed all-reduce over replicated-per-rank grads."""
-    from jax.experimental.shard_map import shard_map
-
     def fn(grads, residuals):
         return compressed_psum_grads(grads, residuals, mesh, axis_names)
 

@@ -27,7 +27,6 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 
 def pipeline_apply(
@@ -58,11 +57,11 @@ def pipeline_apply(
     staged = jax.tree.map(lambda p: p.reshape((s, per_stage) + p.shape[1:]), stacked_params)
 
     @partial(
-        shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(P(stage_axis), P()),  # params sharded by stage, x replicated
         out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )
     def run(stage_params, x_rep):
         stage_params = jax.tree.map(lambda p: p[0], stage_params)  # local (per_stage, ...)

@@ -1,5 +1,5 @@
 from . import dma_model, ops, ref
-from .common import pad2, resolve_interpret, validate_low_bits
+from .common import NativeLoweringUnsupported, pad2, resolve_interpret, validate_low_bits
 from .diff_encode import LOW_BIT_MAX, diff_encode
 from .ditto_diff_matmul import ditto_diff_matmul
 from .fused_step import diff_encode_fused, ditto_fused_matmul, hold_maps
@@ -11,6 +11,7 @@ __all__ = [
     "ops",
     "ref",
     "LOW_BIT_MAX",
+    "NativeLoweringUnsupported",
     "diff_encode",
     "diff_encode_fused",
     "ditto_diff_matmul",

@@ -12,13 +12,17 @@ every Pallas wrapper in this package repeats:
   ``ValueError`` at the ops boundary beats an assert deep in a jitted
   kernel: a bad value (say ``low_bits=2``) would otherwise silently take
   the int8 branch or trip an opaque trace-time assert.
+* :class:`NativeLoweringUnsupported` — the typed refusal of a kernel
+  variant that has no native Mosaic lowering (raised at the ops boundary
+  instead of running the interpreter or another lowering).
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
 
-__all__ = ["DEFAULT_LOW_BITS", "resolve_interpret", "pad2", "validate_low_bits"]
+__all__ = ["DEFAULT_LOW_BITS", "NativeLoweringUnsupported", "resolve_interpret", "pad2",
+           "validate_low_bits"]
 
 #: The int8-everywhere default; DittoPlan.low_bits and every kernel
 #: signature share this one constant so the defaults cannot drift.
@@ -46,3 +50,14 @@ def validate_low_bits(low_bits: int) -> int:
             f"low_bits must be 4 (packed-int4 low-tile branch) or 8 (int8), "
             f"got {low_bits!r}")
     return low_bits
+
+
+class NativeLoweringUnsupported(NotImplementedError):
+    """The plan selects a kernel variant that only runs in interpret mode."""
+
+    def __init__(self, kernel: str, knob: str):
+        self.kernel = kernel
+        super().__init__(
+            f"{kernel} has no native TPU lowering ({knob}); serve this plan "
+            f"with the default two-pass kernels or with interpret=True")
+

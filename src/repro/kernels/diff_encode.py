@@ -22,7 +22,10 @@ Encoding Unit feeding the Compute Unit through the pipeline).
 Tile shapes / grid
     Grid (M/bm, K/bk) over (bm, bk) int8 input tiles (128x128 default);
     the output is ONE int32 class per tile, shape (M/bm, K/bk) — the map
-    ``ditto_diff_matmul`` consumes through its scalar-prefetch slot.
+    ``ditto_diff_matmul`` consumes through its scalar-prefetch slot. The
+    map is one whole-array SMEM output that grid step (i, j) writes at
+    [i, j]: a per-tile (1, 1) VMEM block breaks Mosaic's (8, 128) rule
+    for the last two block dims.
 
 128-tile zero-padding contract
     The raw kernel asserts M % bm == K % bk == 0; callers use
@@ -43,6 +46,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+import jax.experimental.pallas.tpu as pltpu
 
 from .common import resolve_interpret
 
@@ -52,7 +56,8 @@ LOW_BIT_MAX = 7  # largest |Δ| a signed 4-bit lane holds; see module docstring
 def _kernel(xt_ref, xp_ref, cls_ref):
     d = xt_ref[...].astype(jnp.int32) - xp_ref[...].astype(jnp.int32)
     amax = jnp.max(jnp.abs(d))
-    cls_ref[0, 0] = jnp.where(amax == 0, 0, jnp.where(amax <= LOW_BIT_MAX, 1, 2)).astype(jnp.int32)
+    cls = jnp.where(amax == 0, 0, jnp.where(amax <= LOW_BIT_MAX, 1, 2)).astype(jnp.int32)
+    cls_ref[pl.program_id(0), pl.program_id(1)] = cls
 
 
 @functools.partial(jax.jit, static_argnames=("bm", "bk", "interpret"))
@@ -79,7 +84,7 @@ def diff_encode(
             pl.BlockSpec((bm, bk), lambda i, j: (i, j)),
             pl.BlockSpec((bm, bk), lambda i, j: (i, j)),
         ],
-        out_specs=pl.BlockSpec((1, 1), lambda i, j: (i, j)),
+        out_specs=pl.BlockSpec(memory_space=pltpu.SMEM),
         out_shape=jax.ShapeDtypeStruct((m // bm, k // bk), jnp.int32),
         interpret=interpret,
     )(x_t, x_prev)

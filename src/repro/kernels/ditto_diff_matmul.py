@@ -6,8 +6,10 @@ TPU adaptation of the paper's zero-skipping adder-tree PE (PAPER.md):
 the grid runs over (M/bm, N/bn, K/bk); for each (i, kk) the per-tile class
 from ``diff_encode`` gates the MXU contribution with ``@pl.when`` — a
 zero-class tile issues NO dot (its Δ is all-zero, so skipping is exact).
-The Δ is recomputed in VMEM from the int8 operands (subtract-on-the-fly),
-so no Δ tensor ever lands in HBM.
+Δ spans [-254, 254] and does not fit int8, and the MXU takes int8
+operands, so a non-zero tile contributes ``x_t·W - x_prev·W``: two
+int8 x int8 dots, each exact in the int32 accumulator. No Δ tensor ever
+lands in HBM, and no operand is widened before the MXU.
 
 ``classes`` rides the scalar-prefetch slot (PrefetchScalarGridSpec) so a
 production TPU lowering can in principle skip the HBM->VMEM copies of
@@ -27,11 +29,11 @@ int4 low-tile execution branch (``low_bits=4``)
     class-1 verdict guarantees — the int4 branch is BIT-IDENTICAL to the
     int8 branch on every class-1 tile (tests/test_kernel_properties.py
     proves this across the shape matrix). Class-2 tiles always take the
-    full int8 dot. With the default ``low_bits=8`` the class-1/class-2
-    predicate stays merged and low tiles run int8 (the pre-int4 behavior);
-    an int4-native backend consumes the packed words directly at one
-    4-bit multiplier lane per MAC, which is what the cost model prices
-    from the measured tile-class mix.
+    two int8 dots. With the default ``low_bits=8`` the class-1/class-2
+    predicate stays merged and low tiles run int8 (the pre-int4 behavior).
+    The int4 branch runs in interpret mode only: Mosaic refuses its
+    lane-splitting reshape, and ``ops.ditto_linear_step`` refuses
+    ``low_bits=4`` under native lowering.
 
 Optional y_prev operand
     ``y_prev=None`` drops the (bm, bn) int32 y_prev operand entirely —
@@ -131,8 +133,11 @@ def _kernel(cls_ref, xt_ref, xp_ref, w_ref, *rest, n_k: int, split_low: bool,
 
     @pl.when(tile_cls == 2 if split_low else tile_cls > 0)
     def _accum_full():
-        d = xt_ref[...].astype(jnp.int32) - xp_ref[...].astype(jnp.int32)
-        acc_ref[...] += _dot_w(d, w_ref[...].astype(jnp.int32), w_t=w_t)
+        # Δ spans [-254, 254] and does not fit int8, so the tile runs as two
+        # int8 x int8 MXU dots: Δ @ W = x_t @ W - x_prev @ W, exact in int32
+        w = w_ref[...]
+        acc_ref[...] += (_dot_w(xt_ref[...], w, w_t=w_t)
+                         - _dot_w(xp_ref[...], w, w_t=w_t))
 
     if split_low:
 

@@ -19,11 +19,13 @@ bit-identical to the int8 branch (property-tested in
 tests/test_kernel_properties.py).
 
 On an int4-capable backend the packed word feeds two 4-bit multiplier
-lanes directly (the Ditto PE of the paper); on v5e-class TPUs the kernel
-unpacks in VMEM and runs the MXU int8 dot, so the packed form is the
-half-width storage/register format rather than a MAC-rate win — the
-cost-model (``core.ditto.bops`` / ``hwmodel``) prices the 4-bit lanes from
-the measured tile-class mix.
+lanes directly (the Ditto PE of the paper). The kernels that consume it
+here (the ``low_bits=4`` branch of ``ditto_diff_matmul`` and
+``fused_step``) run in interpret mode only: Mosaic refuses their even/odd
+K-lane reshape, so under native TPU lowering ``ops.ditto_linear_step``
+refuses them with ``NativeLoweringUnsupported``. The cost model
+(``core.ditto.bops`` / ``hwmodel``) prices the 4-bit lanes from the
+measured tile-class mix.
 """
 from __future__ import annotations
 

@@ -41,11 +41,8 @@ def _kernel(x_ref, w_ref, o_ref, acc_ref, *, n_k: int):
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    acc_ref[...] += jax.lax.dot(
-        x_ref[...].astype(jnp.int32),
-        w_ref[...].astype(jnp.int32),
-        preferred_element_type=jnp.int32,
-    )
+    # int8 x int8 operands straight into the MXU; int32 accumulation
+    acc_ref[...] += jax.lax.dot(x_ref[...], w_ref[...], preferred_element_type=jnp.int32)
 
     @pl.when(k == n_k - 1)
     def _store():

@@ -30,7 +30,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from .common import DEFAULT_LOW_BITS, pad2, resolve_interpret, validate_low_bits
+from .common import (DEFAULT_LOW_BITS, NativeLoweringUnsupported, pad2, resolve_interpret,
+                     validate_low_bits)
 from .diff_encode import diff_encode
 from .ditto_diff_matmul import ditto_diff_matmul
 from .fused_step import diff_encode_fused, ditto_fused_matmul
@@ -111,11 +112,19 @@ def ditto_linear_step(
     always executes class-1 tiles from the int4-packed Δ-cache (that is
     its storage format) — bit-identical either way (the class-1 verdict
     bounds |Δ| inside the exact pack/unpack range).
+
+    Both of those variants run in interpret mode only: under native
+    lowering they raise ``NativeLoweringUnsupported`` naming the refused
+    kernel, and nothing falls back to another lowering.
     """
     bm, bn, bk, interpret, low_bits, fused = _plan_knobs(
         plan, bm, bn, bk, interpret, low_bits, fused)
     validate_low_bits(low_bits)
     interpret = resolve_interpret(interpret)
+    if not interpret and fused:
+        raise NativeLoweringUnsupported("diff_encode_fused/ditto_fused_matmul", "fused=True")
+    if not interpret and low_bits == 4:
+        raise NativeLoweringUnsupported("ditto_diff_matmul int4 branch", "low_bits=4")
     m, k = x_t_q.shape
     n = w_q.shape[0] if w_transposed else w_q.shape[1]
     xt = pad2(x_t_q, bm, bk)

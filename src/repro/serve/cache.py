@@ -359,6 +359,11 @@ class CompiledRunnerCache:
     def __len__(self) -> int:
         return len(self._steps)
 
+    def executables(self) -> dict:
+        """``{RunnerKey: executable}`` for the steps :meth:`warmup` compiled."""
+        with self._lock:
+            return {k: r.aot_exe for k, r in self._steps.items() if r.aot_exe is not None}
+
     def stats(self) -> dict[str, Any]:
         return {"runners": len(self._steps), "traces": self.n_traces,
                 "hits": self.hits, "misses": self.misses,

@@ -89,3 +89,23 @@ def test_quantized_matmul_scales(key):
     y = ops.quantized_matmul(xq.q, wq.q, xq.scale, wq.scale)
     rel = float(jnp.linalg.norm(y - x @ w) / jnp.linalg.norm(x @ w))
     assert rel < 0.05, rel
+
+
+@pytest.mark.parametrize("knobs,kernel", [({"fused": True}, "ditto_fused_matmul"),
+                                          ({"low_bits": 4}, "int4 branch")],
+                         ids=["fused", "int4"])
+def test_native_lowering_refuses_interpret_only_variants(knobs, kernel):
+    """fused=True and low_bits=4 have no Mosaic lowering: under native
+    lowering the ops boundary raises a typed error naming the kernel, via
+    knobs and via a plan alike, and never falls back."""
+    from repro.core.ditto.plan import DittoPlan
+    from repro.kernels import NativeLoweringUnsupported
+
+    x = jnp.zeros((128, 128), jnp.int8)
+    with pytest.raises(NativeLoweringUnsupported, match=kernel):
+        ops.ditto_linear_step(x, x, x, interpret=False, **knobs)
+    with pytest.raises(NativeLoweringUnsupported, match=kernel):
+        ops.attention_delta(x, x, x, x, jnp.zeros((128, 128), jnp.int32),
+                            plan=DittoPlan(interpret=False, **knobs))
+    y, _ = ops.ditto_linear_step(x, x, x, interpret=True, **knobs)  # interpreter: allowed
+    assert not np.asarray(y).any()

@@ -47,6 +47,30 @@ def test_plan_mesh_validation():
         DittoPlan(mesh_devices=2, mesh_axis="not an identifier")
 
 
+def test_sharded_plan_refused_under_native_lowering():
+    """dp > 1 under native lowering: GSPMD cannot partition a Mosaic
+    kernel, so stamping or stepping such a plan raises a typed error
+    instead of gathering the batch onto every chip."""
+    from repro.analysis import trace_audit as ta
+    from repro.core.ditto import dit_runner
+    from repro.distributed.sharding import (ShardedLoweringUnsupported,
+                                            require_partitionable)
+    from repro.nn import dit as dit_mod
+
+    native = DittoPlan(interpret=False, mesh_devices=4)
+    with pytest.raises(ShardedLoweringUnsupported, match="mesh_devices=4"):
+        require_partitionable(native)
+    cfg = dit_mod.DiTCfg(d_model=16, n_layers=1, n_heads=2, patch=2, in_channels=2,
+                         input_size=4, n_classes=2)
+    with pytest.raises(ShardedLoweringUnsupported):
+        dit_runner.make_step_fn(cfg, ta.uniform_modes(cfg, "act"), native)
+    # one device per dispatch, or the interpreter, partitions fine
+    require_partitionable(native.replace(mesh_devices=1))
+    require_partitionable(native.replace(interpret=True))
+    require_partitionable(DittoPlan(interpret=False))
+    assert ServeMesh(1).plan_for(DittoPlan(interpret=False)).mesh_sig() == (1, "data")
+
+
 def test_mesh_sig_is_trace_identity():
     base = DittoPlan(collect_stats=False)
     meshed = base.replace(mesh_devices=2)

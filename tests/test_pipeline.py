@@ -5,6 +5,7 @@ import numpy as np
 import pytest
 
 from repro.distributed.pipeline import pipeline_apply
+from repro.launch.mesh import auto_mesh
 
 
 @pytest.mark.skipif(len(jax.devices()) != 1, reason="uses all local devices as one stage axis")
@@ -12,7 +13,7 @@ def test_pipeline_matches_sequential(key):
     # 1 real device -> stage axis of size 1 degenerates; emulate 2 stages
     # via a 2-device mesh only when available, else the S=1 path.
     n = len(jax.devices())
-    mesh = jax.make_mesh((n,), ("pod",))
+    mesh = auto_mesh((n,), ("pod",))
     L, B, D = 4, 8, 16
     ws = jax.random.normal(key, (L, D, D)) * 0.3
 
@@ -42,7 +43,8 @@ import jax, jax.numpy as jnp, numpy as np, sys
 sys.path.insert(0, "src")
 from repro.distributed.pipeline import pipeline_apply
 key = jax.random.PRNGKey(0)
-mesh = jax.make_mesh((4,), ("pod",))
+from repro.launch.mesh import auto_mesh
+mesh = auto_mesh((4,), ("pod",))
 L, B, D = 8, 8, 16
 ws = jax.random.normal(key, (L, D, D)) * 0.3
 def layer_fn(w, x):

@@ -18,6 +18,7 @@ from repro.core import diffusion
 from repro.core.ditto import DittoEngine, make_denoise_fn
 from repro.data.synthetic import DataCfg, batch_for
 from repro.launch import steps as steps_mod
+from repro.launch.mesh import make_test_mesh
 from repro.nn import dit as dit_mod
 
 pytestmark = pytest.mark.slow
@@ -102,7 +103,7 @@ def test_sharded_train_step_small_mesh(trained_tiny_dit):
     from repro.distributed import sharding as sh
 
     arch = configs.get("qwen3-0.6b").smoke()
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_test_mesh(1)
     rules = sh.make_rules(arch)
     shard = sh.make_shard_fn(rules, mesh)
     opt = steps_mod.make_optimizer(arch, total=10)
