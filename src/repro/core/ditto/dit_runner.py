@@ -26,7 +26,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ...distributed.sharding import constrain_batch, require_partitionable
+from ...distributed.sharding import constrain_batch
 from ...kernels.common import DEFAULT_LOW_BITS
 from ...nn import core as nncore
 from ...nn import dit as dit_mod
@@ -202,7 +202,6 @@ def make_step_fn(cfg: dit_mod.DiTCfg, modes: dict[str, str], plan: DittoPlan | N
     # MESH_SIG_FIELDS are cache_sig() fields. mesh_sig=None leaves the
     # jaxpr untouched (bit-for-bit the pre-mesh trace).
     msig = plan.mesh_sig()
-    require_partitionable(plan)
 
     def step(dparams, mparams, state, latents, t, labels):
         latents = constrain_batch(latents, msig)

@@ -42,7 +42,8 @@ from __future__ import annotations
 import dataclasses
 import warnings
 
-from ...kernels.common import DEFAULT_LOW_BITS, resolve_interpret, validate_low_bits
+from ...kernels.common import (DEFAULT_LOW_BITS, ShardedLoweringUnsupported,
+                               require_native_kernels, resolve_interpret, validate_low_bits)
 
 DEFAULT_MAX_BATCH = 64  # mirrored by repro.serve.bucketing
 
@@ -509,6 +510,26 @@ def segment_view(plan):
         return plan.normalized().segment_plans()
     plan = plan.normalized()
     return ((0, plan.steps, plan),)
+
+
+def require_native_lowering(plan) -> None:
+    """Refuse, before anything is served, a plan (or schedule) that selects
+    a lowering the chip does not have — in any segment and on any rung of
+    its degradation ladder, so a refusal can never surface mid-dispatch as
+    a fault the ladder recovers from. Under native lowering that is a
+    kernel variant :func:`~repro.kernels.common.require_native_kernels`
+    refuses, or a batch sharded over ``mesh_devices > 1`` chips
+    (:class:`ShardedLoweringUnsupported`). Interpreted plans pass."""
+    rungs = plan.fallback_plans() if hasattr(plan, "fallback_plans") else ()
+    for p in [seg for _, _, seg in segment_view(plan)] + list(rungs):
+        if resolve_interpret(p.interpret):
+            continue
+        require_native_kernels(fused=p.fused, low_bits=p.low_bits)
+        if (p.mesh_devices or 1) > 1:
+            raise ShardedLoweringUnsupported(
+                f"mesh_devices={p.mesh_devices} shards one dispatch over "
+                f"{p.mesh_devices} chips, but the Pallas kernels have no "
+                f"partitioning rule; serve with dp=1 (one replica per chip)")
 
 
 # --------------------------------------------------------- deprecation shim

@@ -24,25 +24,7 @@ import numpy as np
 from jax.sharding import AbstractMesh, Mesh, NamedSharding, PartitionSpec as P
 
 from ..configs.base import ArchConfig
-from ..kernels.common import resolve_interpret
 from ..nn import core
-
-
-class ShardedLoweringUnsupported(NotImplementedError):
-    """A batch-sharded (``dp > 1``) step under native lowering. GSPMD cannot
-    partition a Mosaic kernel, and gathering the batch onto every chip
-    would not be data parallelism, so such a plan is refused."""
-
-
-def require_partitionable(plan) -> None:
-    """Raise :class:`ShardedLoweringUnsupported` for a plan whose batch axis
-    spans more than one device while its kernels lower natively."""
-    msig = plan.mesh_sig()
-    if msig is not None and msig[0] > 1 and not resolve_interpret(plan.interpret):
-        raise ShardedLoweringUnsupported(
-            f"mesh_devices={msig[0]} shards one dispatch over {msig[0]} chips, but "
-            f"the Pallas kernels have no partitioning rule; serve with dp=1 "
-            f"(one replica per chip)")
 
 
 def batch_sharding(mesh_sig: tuple, batch: int) -> NamedSharding:

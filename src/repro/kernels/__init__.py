@@ -1,5 +1,6 @@
 from . import dma_model, ops, ref
-from .common import NativeLoweringUnsupported, pad2, resolve_interpret, validate_low_bits
+from .common import (NativeLoweringUnsupported, ShardedLoweringUnsupported, pad2,
+                     resolve_interpret, validate_low_bits)
 from .diff_encode import LOW_BIT_MAX, diff_encode
 from .ditto_diff_matmul import ditto_diff_matmul
 from .fused_step import diff_encode_fused, ditto_fused_matmul, hold_maps
@@ -12,6 +13,7 @@ __all__ = [
     "ref",
     "LOW_BIT_MAX",
     "NativeLoweringUnsupported",
+    "ShardedLoweringUnsupported",
     "diff_encode",
     "diff_encode_fused",
     "ditto_diff_matmul",

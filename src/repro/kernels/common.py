@@ -12,17 +12,21 @@ every Pallas wrapper in this package repeats:
   ``ValueError`` at the ops boundary beats an assert deep in a jitted
   kernel: a bad value (say ``low_bits=2``) would otherwise silently take
   the int8 branch or trip an opaque trace-time assert.
-* :class:`NativeLoweringUnsupported` — the typed refusal of a kernel
-  variant that has no native Mosaic lowering (raised at the ops boundary
-  instead of running the interpreter or another lowering).
+* :func:`require_native_kernels` — the one rule for which kernel
+  variants have a native Mosaic lowering. It raises the typed
+  :class:`NativeLoweringUnsupported` instead of running the interpreter
+  or another lowering; the ops boundary and plan acceptance
+  (``repro.core.ditto.plan.require_native_lowering``) both call it.
+  :class:`ShardedLoweringUnsupported` is the matching refusal of a
+  batch-sharded (``dp > 1``) dispatch.
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
 
-__all__ = ["DEFAULT_LOW_BITS", "NativeLoweringUnsupported", "resolve_interpret", "pad2",
-           "validate_low_bits"]
+__all__ = ["DEFAULT_LOW_BITS", "NativeLoweringUnsupported", "ShardedLoweringUnsupported",
+           "resolve_interpret", "pad2", "require_native_kernels", "validate_low_bits"]
 
 #: The int8-everywhere default; DittoPlan.low_bits and every kernel
 #: signature share this one constant so the defaults cannot drift.
@@ -61,3 +65,18 @@ class NativeLoweringUnsupported(NotImplementedError):
             f"{kernel} has no native TPU lowering ({knob}); serve this plan "
             f"with the default two-pass kernels or with interpret=True")
 
+
+class ShardedLoweringUnsupported(NotImplementedError):
+    """A batch-sharded (``dp > 1``) dispatch under native lowering. GSPMD
+    cannot partition a Mosaic kernel, and gathering the batch onto every
+    chip would not be data parallelism, so such a plan is refused."""
+
+
+def require_native_kernels(*, fused: bool, low_bits: int) -> None:
+    """Raise :class:`NativeLoweringUnsupported` for a diff-step variant
+    with no Mosaic lowering: the fused single-pass kernels and the int4
+    branch both split K lanes with a reshape Mosaic refuses."""
+    if fused:
+        raise NativeLoweringUnsupported("diff_encode_fused/ditto_fused_matmul", "fused=True")
+    if validate_low_bits(low_bits) == 4:
+        raise NativeLoweringUnsupported("ditto_diff_matmul int4 branch", "low_bits=4")

@@ -30,7 +30,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from .common import (DEFAULT_LOW_BITS, NativeLoweringUnsupported, pad2, resolve_interpret,
+from .common import (DEFAULT_LOW_BITS, pad2, require_native_kernels, resolve_interpret,
                      validate_low_bits)
 from .diff_encode import diff_encode
 from .ditto_diff_matmul import ditto_diff_matmul
@@ -121,10 +121,8 @@ def ditto_linear_step(
         plan, bm, bn, bk, interpret, low_bits, fused)
     validate_low_bits(low_bits)
     interpret = resolve_interpret(interpret)
-    if not interpret and fused:
-        raise NativeLoweringUnsupported("diff_encode_fused/ditto_fused_matmul", "fused=True")
-    if not interpret and low_bits == 4:
-        raise NativeLoweringUnsupported("ditto_diff_matmul int4 branch", "low_bits=4")
+    if not interpret:
+        require_native_kernels(fused=fused, low_bits=low_bits)
     m, k = x_t_q.shape
     n = w_q.shape[0] if w_transposed else w_q.shape[1]
     xt = pad2(x_t_q, bm, bk)

@@ -40,8 +40,7 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..core.ditto.plan import DittoPlan, PlanSchedule
-from ..distributed.sharding import (  # noqa: F401  (re-export)
-    ShardedLoweringUnsupported, batch_sharding, require_partitionable)
+from ..distributed.sharding import batch_sharding  # noqa: F401  (re-export)
 
 DEFAULT_AXIS = "data"
 
@@ -135,14 +134,10 @@ class ServeMesh:
 
     def plan_for(self, plan: DittoPlan | PlanSchedule):
         """``plan`` stamped with this mesh's signature (schedules stamp
-        their base — segments inherit; a mid-loop reshard is invalid).
-        Raises ``ShardedLoweringUnsupported`` for ``dp > 1`` under native
-        lowering: the Pallas kernels cannot be partitioned."""
+        their base — segments inherit; a mid-loop reshard is invalid)."""
         if isinstance(plan, PlanSchedule):
             return plan.replace(base=self.plan_for(plan.base))
-        plan = plan.replace(mesh_devices=self.dp, mesh_axis=self.axis)
-        require_partitionable(plan)
-        return plan
+        return plan.replace(mesh_devices=self.dp, mesh_axis=self.axis)
 
     # ------------------------------------------------------------ placement
     def shard_mesh(self, shard: int) -> Mesh:

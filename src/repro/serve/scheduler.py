@@ -114,7 +114,8 @@ import jax.numpy as jnp
 
 from ..core import diffusion
 from ..core.ditto import DittoEngine, make_denoise_fn
-from ..core.ditto.plan import UNSET, DittoPlan, PlanSchedule, is_unset, segment_view
+from ..core.ditto.plan import (UNSET, DittoPlan, PlanSchedule, is_unset,
+                               require_native_lowering, segment_view)
 from . import faults
 from .bucketing import bucket_for
 from .cache import CompiledRunnerCache
@@ -448,7 +449,9 @@ class ServeScheduler:
         default for this request. ``deadline_ms`` overrides the plan's
         latency budget for this request (``None`` = no budget). Full
         ``max_batch`` buckets are dispatched as soon as they fill (unless
-        ``eager=False``)."""
+        ``eager=False``). A plan whose lowering — on any segment or ladder
+        rung — the backend lacks raises here
+        (``core.ditto.plan.require_native_lowering``) and is never queued."""
         if x.shape[0] < 1:
             raise ValueError("empty request")
         plan = plan if plan is not None else self.session.plan
@@ -457,6 +460,10 @@ class ServeScheduler:
             # unstamped override would land in a separate (unsharded)
             # trace-identity group and never share the warmed runners
             plan = self.mesh.plan_for(plan)
+        # a lowering the chip lacks is refused here, on the caller's stack:
+        # raised inside a dispatch it would look like a fault the
+        # degradation ladder recovers from on another lowering
+        require_native_lowering(plan)
         plan = plan.normalized()
         if is_unset(deadline_ms):
             deadline_ms = plan.deadline_ms

@@ -31,7 +31,8 @@ from typing import Any
 
 import jax
 
-from ..core.ditto.plan import UNSET, DittoPlan, PlanSchedule, plan_from_kwargs
+from ..core.ditto.plan import (UNSET, DittoPlan, PlanSchedule, plan_from_kwargs,
+                               require_native_lowering)
 from ..sim import harness
 from . import faults
 from .bucketing import bucket_for
@@ -107,6 +108,12 @@ class ServeSession:
         # mesh-signed plan resolves a default mesh at dispatch time; the
         # params are committed (replicated) onto the submesh once here so
         # every dispatch finds them shard-local.
+        self.plan = plan_from_kwargs("serve.ServeSession", plan, steps=steps,
+                                     sampler=sampler, policy=policy, compiled=compiled,
+                                     interpret=interpret, collect_stats=collect_stats,
+                                     block=block, low_bits=low_bits, fused=fused,
+                                     max_batch=max_batch)
+        require_native_lowering(self.plan)
         self.mesh = mesh
         if mesh is not None:
             from jax.sharding import NamedSharding, PartitionSpec
@@ -114,11 +121,6 @@ class ServeSession:
         self.params = params
         self.cfg = cfg
         self.sched = sched
-        self.plan = plan_from_kwargs("serve.ServeSession", plan, steps=steps,
-                                     sampler=sampler, policy=policy, compiled=compiled,
-                                     interpret=interpret, collect_stats=collect_stats,
-                                     block=block, low_bits=low_bits, fused=fused,
-                                     max_batch=max_batch)
         self.cache = cache if cache is not None else CompiledRunnerCache()
         self.batches_served = 0
         self.requests_served = 0
@@ -137,7 +139,10 @@ class ServeSession:
         fault = faults.fire("session.serve")
         if fault is not None:
             faults.perform(fault)
-        plan = self.plan if plan is None else plan
+        if plan is None:
+            plan = self.plan
+        else:
+            require_native_lowering(plan)
         n = x.shape[0]
         chunks: list[ChunkResult] = []
         samples = []

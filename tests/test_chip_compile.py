@@ -7,6 +7,8 @@ kernel (``tpu_custom_call``). Nothing runs: this catches what the chip's
 compiler refuses (block layouts, operand dtypes, VMEM) at no chip time.
 The topology is described inside a fixture, never at import.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -86,32 +88,33 @@ def test_attention_delta_compiles(one_chip):
     assert "tpu_custom_call" in text
 
 
-@pytest.mark.parametrize("mode", ["act", "diff"])
-def test_one_layer_serving_step_compiles(one_chip, mode):
-    """The whole jitted serving step (one DiT-XL/2 block at full width,
-    bucket 4) — the program the runner cache compiles per layer mode."""
+def _serving_step_text(one_chip, mode: str, bucket: int) -> str:
+    """Compiled HLO of the whole jitted serving step (one DiT-XL/2 block at
+    full width) at ``bucket`` rows — the program the runner cache compiles."""
     cfg = dit_mod.DiTCfg(d_model=D, n_layers=1, n_heads=16, n_classes=1000)
     plan = DittoPlan(interpret=False, collect_stats=False)
     step = dit_runner.make_step_fn(cfg, ta.uniform_modes(cfg, mode), plan)
-    dparams, mparams, lat, t, labels = ta.abstract_inputs(cfg, 4)
-    state = ta.abstract_state(cfg, 4)
+    dparams, mparams, lat, t, labels = ta.abstract_inputs(cfg, bucket)
+    state = ta.abstract_state(cfg, bucket)
     args = jax.tree.map(lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
                         (dparams, mparams, state, lat, t, labels))
-    text = jax.jit(step).lower(*args).compile().as_text()
-    assert "tpu_custom_call" in text
+    return jax.jit(step).lower(*args).compile().as_text()
+
+
+@pytest.mark.parametrize("mode", ["act", "diff"])
+def test_one_layer_serving_step_compiles(one_chip, mode):
+    assert "tpu_custom_call" in _serving_step_text(one_chip, mode, 4)
 
 
 def test_one_row_serving_step_keeps_float_matmuls_on_the_mxu(one_chip):
-    """At bucket 1 XLA:TPU would lower the conditioning matmuls as f32
-    multiply-reduces, which round unlike the MXU path every larger bucket
-    takes; a sample must not change with the batch it is served in."""
-    cfg = dit_mod.DiTCfg(d_model=D, n_layers=1, n_heads=16, n_classes=1000)
-    plan = DittoPlan(interpret=False, collect_stats=False)
-    step = dit_runner.make_step_fn(cfg, ta.uniform_modes(cfg, "act"), plan)
-    dparams, mparams, lat, t, labels = ta.abstract_inputs(cfg, 1)
-    state = ta.abstract_state(cfg, 1)
-    args = jax.tree.map(lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
-                        (dparams, mparams, state, lat, t, labels))
-    text = jax.jit(step).lower(*args).compile().as_text()
-    reduced_dots = [ln for ln in text.splitlines() if " reduce(" in ln and "dot_general" in ln]
-    assert not reduced_dots, reduced_dots
+    """XLA:TPU lowers a one-row f32 matmul as a multiply-reduce, which
+    rounds unlike the MXU convolution every larger bucket takes; a sample
+    must not change with the batch it is served in. So bucket 1 must lower
+    exactly as many float matmuls to MXU convolutions as bucket 2 (the
+    Ditto GEMMs are Mosaic kernels, not convolutions). Without
+    ``dit_runner._cond_dense`` bucket 1 keeps 1 of bucket 2's 4."""
+    def convolutions(text):
+        return len(re.findall(r"= \S+ convolution\(", text))
+
+    one, two = (convolutions(_serving_step_text(one_chip, "act", b)) for b in (1, 2))
+    assert one == two > 0, (one, two)

@@ -207,6 +207,29 @@ def test_ladder_falls_back_on_retry():
     s.close()
 
 
+@pytest.mark.parametrize("plan,kernel", [
+    (PLAN.replace(interpret=False, fused=True, max_retries=2,
+                  fallbacks=(dict(fused=False),)), "ditto_fused_matmul"),
+    (PLAN.replace(interpret=False, max_retries=1,
+                  fallbacks=(dict(low_bits=4),)), "int4 branch"),
+], ids=["fused_plan", "int4_rung"])
+def test_native_refusal_is_never_recovered_by_the_ladder(plan, kernel):
+    """A lowering the backend lacks — on the plan or on any ladder rung —
+    is refused when the plan is accepted, with the typed error on the
+    caller's stack; nothing is served, retried or counted as a recovery."""
+    from repro.kernels import NativeLoweringUnsupported
+
+    s = _fake_scheduler()
+    with pytest.raises(NativeLoweringUnsupported, match=kernel):
+        s.submit(*_request(4, 2), plan=plan)
+    st = s.stats()
+    assert s.session.calls == [] and st["submitted"] == 0
+    assert st["retries"] == 0 and st["fallback_dispatches"] == 0
+    with pytest.raises(NativeLoweringUnsupported, match=kernel):
+        ServeSession(None, CFG, None, plan)
+    s.close()
+
+
 def test_ladder_exhaustion_is_typed():
     plan = PLAN.replace(max_retries=2, fallbacks=(dict(fused=False),))
     s = _fake_scheduler(plan=plan)

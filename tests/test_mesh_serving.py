@@ -49,25 +49,23 @@ def test_plan_mesh_validation():
 
 def test_sharded_plan_refused_under_native_lowering():
     """dp > 1 under native lowering: GSPMD cannot partition a Mosaic
-    kernel, so stamping or stepping such a plan raises a typed error
-    instead of gathering the batch onto every chip."""
-    from repro.analysis import trace_audit as ta
-    from repro.core.ditto import dit_runner
-    from repro.distributed.sharding import (ShardedLoweringUnsupported,
-                                            require_partitionable)
-    from repro.nn import dit as dit_mod
+    kernel, so accepting such a plan raises a typed error instead of
+    gathering the batch onto every chip."""
+    from repro.core.ditto.plan import require_native_lowering
+    from repro.kernels import ShardedLoweringUnsupported
+    from repro.serve import ServeSession
 
     native = DittoPlan(interpret=False, mesh_devices=4)
     with pytest.raises(ShardedLoweringUnsupported, match="mesh_devices=4"):
-        require_partitionable(native)
-    cfg = dit_mod.DiTCfg(d_model=16, n_layers=1, n_heads=2, patch=2, in_channels=2,
-                         input_size=4, n_classes=2)
+        require_native_lowering(native)
     with pytest.raises(ShardedLoweringUnsupported):
-        dit_runner.make_step_fn(cfg, ta.uniform_modes(cfg, "act"), native)
+        require_native_lowering(PlanSchedule(native, [(0, native.steps, {})]))
+    with pytest.raises(ShardedLoweringUnsupported):  # refused before params are placed
+        ServeSession(None, None, None, native)
     # one device per dispatch, or the interpreter, partitions fine
-    require_partitionable(native.replace(mesh_devices=1))
-    require_partitionable(native.replace(interpret=True))
-    require_partitionable(DittoPlan(interpret=False))
+    require_native_lowering(native.replace(mesh_devices=1))
+    require_native_lowering(native.replace(interpret=True))
+    require_native_lowering(DittoPlan(interpret=False))
     assert ServeMesh(1).plan_for(DittoPlan(interpret=False)).mesh_sig() == (1, "data")
 
 
