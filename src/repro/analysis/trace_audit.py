@@ -184,19 +184,23 @@ def uniform_modes(cfg, mode: str) -> dict[str, str]:
 
 def abstract_state(cfg, batch: int):
     """Bootstrap the temporal-state pytree shape with one ``eval_shape``:
-    under all-``act`` modes with ``collect_stats=False`` the step never
-    READS its state argument, so an empty-dict state traces fine and the
-    returned ``new_state`` IS the true state shape tree (the engine writes
-    every field regardless of mode)."""
+    under all-``act`` modes with ``collect_stats=False`` the step reads no
+    layer's state (only the tile totals, which it passes through), so
+    empty per-layer dicts trace fine and the returned ``new_state`` IS the
+    true state shape tree (the engine writes every field regardless of
+    mode)."""
     import jax
+    import jax.numpy as jnp
 
     from repro.core.ditto import dit_runner
+    from repro.core.ditto.compiled import TILE_TOTALS
     from repro.core.ditto.plan import DittoPlan
 
     dparams, mparams, lat, t, labels = abstract_inputs(cfg, batch)
-    step = dit_runner.make_step_fn(cfg, uniform_modes(cfg, "act"),
-                                   DittoPlan(collect_stats=False))
-    dummy = {nm: {} for nm in uniform_modes(cfg, "act")}
+    modes = uniform_modes(cfg, "act")
+    step = dit_runner.make_step_fn(cfg, modes, DittoPlan(collect_stats=False))
+    dummy = {nm: {} for nm in modes}
+    dummy[TILE_TOTALS] = jax.ShapeDtypeStruct((len(modes), 3), jnp.int32)
     _, state_shapes, _ = jax.eval_shape(step, dparams, mparams, dummy, lat, t, labels)
     return state_shapes
 

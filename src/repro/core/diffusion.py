@@ -3,6 +3,7 @@
 The samplers drive a generic ``denoise_fn(x_t, t, labels) -> eps_hat``;
 Ditto wraps that callable with temporal-difference processing (the
 iterative sampler loop is exactly the temporal axis the paper exploits).
+Each sampler step is a ``diffusion.step`` host span (:mod:`repro.core.spans`).
 """
 from __future__ import annotations
 
@@ -10,6 +11,8 @@ import dataclasses
 
 import jax
 import jax.numpy as jnp
+
+from .spans import span
 
 
 @dataclasses.dataclass(frozen=True)
@@ -70,13 +73,14 @@ def ddim_sample(sched: NoiseSchedule, denoise_fn, x_T, *, steps: int, labels=Non
     ts = ddim_timesteps(sched.T, steps)
     x = x_T
     for i in range(len(ts)):
-        t = int(ts[i])
-        t_prev = int(ts[i + 1]) if i + 1 < len(ts) else -1
-        t_vec = jnp.full((x.shape[0],), t, jnp.int32)
-        eps_hat = denoise_fn(x, t_vec, labels)
-        x = ddim_step(sched, x, eps_hat, t, t_prev)
-        if callback is not None:
-            callback(step_index=i, t=t, x=x)
+        with span("diffusion.step", step=i):
+            t = int(ts[i])
+            t_prev = int(ts[i + 1]) if i + 1 < len(ts) else -1
+            t_vec = jnp.full((x.shape[0],), t, jnp.int32)
+            eps_hat = denoise_fn(x, t_vec, labels)
+            x = ddim_step(sched, x, eps_hat, t, t_prev)
+            if callback is not None:
+                callback(step_index=i, t=t, x=x)
     return x
 
 
@@ -86,24 +90,26 @@ def plms_sample(sched: NoiseSchedule, denoise_fn, x_T, *, steps: int, labels=Non
     x = x_T
     eps_hist: list = []
     for i in range(len(ts)):
-        t = int(ts[i])
-        t_prev = int(ts[i + 1]) if i + 1 < len(ts) else -1
-        t_vec = jnp.full((x.shape[0],), t, jnp.int32)
-        eps = denoise_fn(x, t_vec, labels)
-        if len(eps_hist) == 0:
-            eps_prime = eps
-        elif len(eps_hist) == 1:
-            eps_prime = (3 * eps - eps_hist[-1]) / 2
-        elif len(eps_hist) == 2:
-            eps_prime = (23 * eps - 16 * eps_hist[-1] + 5 * eps_hist[-2]) / 12
-        else:
-            eps_prime = (55 * eps - 59 * eps_hist[-1] + 37 * eps_hist[-2] - 9 * eps_hist[-3]) / 24
-        eps_hist.append(eps)
-        if len(eps_hist) > 3:
-            eps_hist.pop(0)
-        x = ddim_step(sched, x, eps_prime, t, t_prev)
-        if callback is not None:
-            callback(step_index=i, t=t, x=x)
+        with span("diffusion.step", step=i):
+            t = int(ts[i])
+            t_prev = int(ts[i + 1]) if i + 1 < len(ts) else -1
+            t_vec = jnp.full((x.shape[0],), t, jnp.int32)
+            eps = denoise_fn(x, t_vec, labels)
+            if len(eps_hist) == 0:
+                eps_prime = eps
+            elif len(eps_hist) == 1:
+                eps_prime = (3 * eps - eps_hist[-1]) / 2
+            elif len(eps_hist) == 2:
+                eps_prime = (23 * eps - 16 * eps_hist[-1] + 5 * eps_hist[-2]) / 12
+            else:
+                eps_prime = (55 * eps - 59 * eps_hist[-1] + 37 * eps_hist[-2]
+                             - 9 * eps_hist[-3]) / 24
+            eps_hist.append(eps)
+            if len(eps_hist) > 3:
+                eps_hist.pop(0)
+            x = ddim_step(sched, x, eps_prime, t, t_prev)
+            if callback is not None:
+                callback(step_index=i, t=t, x=x)
     return x
 
 

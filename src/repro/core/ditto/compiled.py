@@ -45,7 +45,10 @@ With ``collect_stats=True`` the step also reduces zero/low/full class
 fractions on-device and returns them as an aux pytree; the host engine
 synthesizes cost-model records from them (``record_compiled_step``) so the
 design-point simulator keeps working across compiled steps. Set it False
-for the pure serving fast path.
+for the pure serving fast path. Either way each diff layer's measured
+tile-class histogram (``tile_hist``) is in the aux pytree, and the step
+adds it to the state's :data:`TILE_TOTALS` leaf, a device-side counter the
+host reads once per sample.
 """
 from __future__ import annotations
 
@@ -56,6 +59,10 @@ from ...kernels import ops
 from . import classify, quant
 from .engine import DittoEngine
 from .plan import UNSET, DittoPlan, plan_from_kwargs, segment_resolved
+
+#: state key of the (layers, 3) int32 running (zero, low, full) tile totals,
+#: one row per layer in sorted name order
+TILE_TOTALS = "tile_totals"
 
 
 def _class_fractions(d: jax.Array) -> tuple:
@@ -112,8 +119,7 @@ def linear_apply(p: dict, mode: str, x: jax.Array, st: dict, *,
     if mode == "diff":
         y_i32, classes = ops.ditto_linear_step(q_t, st["x_prev"], p["w_q"], st["y_prev"],
                                                plan=plan)
-        if collect_stats:
-            aux["tile_hist"] = _tile_hist(classes)
+        aux["tile_hist"] = _tile_hist(classes)
     else:  # act, and spatial (whose eager branch computes the direct GEMM)
         y_i32 = ops.int8_act_matmul(q_t, p["w_q"], plan=plan)
     if collect_stats:
@@ -163,16 +169,11 @@ def attention_apply(p: dict, mode: str, a: jax.Array, b: jax.Array, st: dict, *,
             qa_i, qb_i, ap_i, bp_i, yp_i = ins
             y_i, (cls_dk, cls_dq) = ops.attention_delta(qa_i, ap_i, qb_i, bp_i, yp_i,
                                                         plan=plan)
-            if collect_stats:  # trace-static, mirrors the linear path
-                return c, (y_i, _tile_hist(cls_dk) + _tile_hist(cls_dq))
-            return c, y_i
+            return c, (y_i, _tile_hist(cls_dk) + _tile_hist(cls_dq))
 
         xs = (qa, qb, st["a_prev"], st["b_prev"], st["y_prev"])
-        if collect_stats:
-            _, (y_i32, hists) = jax.lax.scan(body, 0, xs)
-            aux["tile_hist"] = hists.sum(axis=0)  # both sub-ops, all scan elems
-        else:
-            _, y_i32 = jax.lax.scan(body, 0, xs)
+        _, (y_i32, hists) = jax.lax.scan(body, 0, xs)
+        aux["tile_hist"] = hists.sum(axis=0)  # both sub-ops, all scan elems
     else:
         def body(c, ins):
             qa_i, qb_i = ins
@@ -188,6 +189,19 @@ def attention_apply(p: dict, mode: str, a: jax.Array, b: jax.Array, st: dict, *,
     new_st = dict(a_prev=qa, b_prev=qb, y_prev=y_i32)
     y = y_i32.astype(jnp.float32) * p["a_scale"] * p["b_scale"]
     return y.reshape(lead + (m, n)), new_st, aux
+
+
+def _placed_like_step_output(x: jax.Array, ref: jax.Array) -> jax.Array:
+    """``x`` placed where the jitted step returns an unsharded leaf when its
+    state is placed like ``ref``: replicated on ``ref``'s mesh if ``ref`` is
+    committed, else left uncommitted. The first compiled step then sees the
+    same argument placements as every later one, so it compiles once."""
+    if not ref.committed:
+        return x
+    sharding = ref.sharding
+    if isinstance(sharding, jax.sharding.NamedSharding):
+        sharding = jax.sharding.NamedSharding(sharding.mesh, jax.sharding.PartitionSpec())
+    return jax.device_put(x, sharding)
 
 
 class CompiledDittoEngine:
@@ -223,13 +237,17 @@ class CompiledDittoEngine:
     # ---------------------------------------------------------------- state
     def init_state(self) -> dict:
         """Initial temporal state = the eager engine's state after its last
-        calibration step (int8 x_prev / int32 y_prev per layer)."""
-        state: dict[str, dict] = {}
+        calibration step (int8 x_prev / int32 y_prev per layer), plus zeroed
+        :data:`TILE_TOTALS`."""
+        state: dict = {}
         for name, st in self.engine.layers.items():
             if st.w is not None:
                 state[name] = dict(x_prev=st.x_prev, y_prev=st.y_prev)
             else:
                 state[name] = dict(a_prev=st.a_prev, b_prev=st.b_prev, y_prev=st.y_prev)
+        state[TILE_TOTALS] = _placed_like_step_output(
+            jnp.zeros((len(self.engine.layers), 3), jnp.int32),
+            jax.tree_util.tree_leaves(state)[0])
         return state
 
     # ------------------------------------------------- plan-field accessors

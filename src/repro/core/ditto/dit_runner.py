@@ -30,9 +30,10 @@ from ...distributed.sharding import constrain_batch
 from ...kernels.common import DEFAULT_LOW_BITS
 from ...nn import core as nncore
 from ...nn import dit as dit_mod
+from ..spans import span
 from . import compiled as compiled_mod
 from . import defo
-from .compiled import CompiledDittoEngine
+from .compiled import TILE_TOTALS, CompiledDittoEngine
 from .engine import DittoEngine, LayerMeta
 from .plan import (EAGER_PLAN, UNSET, DittoPlan, PlanSchedule, is_unset,
                    plan_from_kwargs, segment_resolved)
@@ -81,6 +82,8 @@ def _dit_forward(params, cfg: dit_mod.DiTCfg, linear, attention, latents, t, lab
     ``linear(name, x)`` and ``attention(name, a, b)`` are the engine ops —
     eager (stateful) or compiled (closures threading a state pytree).
     Patch embed / conditioning / norms / softmax stay fp32 (VPU-side ops).
+    Each block runs under ``jax.named_scope("blk<i>")`` with ``attn`` and
+    ``mlp`` sub-scopes, so device ops carry their layer in their metadata.
     """
     b, hh, ww, ch = latents.shape
     pp = cfg.patch
@@ -98,24 +101,28 @@ def _dit_forward(params, cfg: dit_mod.DiTCfg, linear, attention, latents, t, lab
     scale = 1.0 / math.sqrt(hd)
     for i in range(cfg.n_layers):
         bk = f"blk{i}"
-        mod = linear(f"{bk}.mod", c_act)
-        sh_a, sc_a, g_a, sh_m, sc_m, g_m = jnp.split(mod, 6, axis=-1)
-        h = dit_mod._modulate(dit_mod._ln(x), sh_a, sc_a)
-        q = linear(f"{bk}.wq", h).reshape(b, cfg.n_tokens, nh, hd)
-        k = linear(f"{bk}.wk", h).reshape(b, cfg.n_tokens, nh, hd)
-        v = linear(f"{bk}.wv", h).reshape(b, cfg.n_tokens, nh, hd)
-        qf = q.transpose(0, 2, 1, 3).reshape(b * nh, cfg.n_tokens, hd)
-        kf = k.transpose(0, 2, 1, 3).reshape(b * nh, cfg.n_tokens, hd)
-        vf = v.transpose(0, 2, 1, 3).reshape(b * nh, cfg.n_tokens, hd)
-        scores = attention(f"{bk}.qk", qf, kf) * scale
-        probs = jax.nn.softmax(scores, axis=-1)
-        av = attention(f"{bk}.pv", probs, vf.swapaxes(-1, -2))
-        av = av.reshape(b, nh, cfg.n_tokens, hd).transpose(0, 2, 1, 3).reshape(b, cfg.n_tokens, nh * hd)
-        a = linear(f"{bk}.wo", av)
-        x = x + g_a[:, None, :] * a
-        h = dit_mod._modulate(dit_mod._ln(x), sh_m, sc_m)
-        hmid = jax.nn.gelu(linear(f"{bk}.wi", h))
-        x = x + g_m[:, None, :] * linear(f"{bk}.wd", hmid)
+        with jax.named_scope(bk):
+            mod = linear(f"{bk}.mod", c_act)
+            sh_a, sc_a, g_a, sh_m, sc_m, g_m = jnp.split(mod, 6, axis=-1)
+            with jax.named_scope("attn"):
+                h = dit_mod._modulate(dit_mod._ln(x), sh_a, sc_a)
+                q = linear(f"{bk}.wq", h).reshape(b, cfg.n_tokens, nh, hd)
+                k = linear(f"{bk}.wk", h).reshape(b, cfg.n_tokens, nh, hd)
+                v = linear(f"{bk}.wv", h).reshape(b, cfg.n_tokens, nh, hd)
+                qf = q.transpose(0, 2, 1, 3).reshape(b * nh, cfg.n_tokens, hd)
+                kf = k.transpose(0, 2, 1, 3).reshape(b * nh, cfg.n_tokens, hd)
+                vf = v.transpose(0, 2, 1, 3).reshape(b * nh, cfg.n_tokens, hd)
+                scores = attention(f"{bk}.qk", qf, kf) * scale
+                probs = jax.nn.softmax(scores, axis=-1)
+                av = attention(f"{bk}.pv", probs, vf.swapaxes(-1, -2))
+                av = av.reshape(b, nh, cfg.n_tokens, hd).transpose(0, 2, 1, 3).reshape(
+                    b, cfg.n_tokens, nh * hd)
+                a = linear(f"{bk}.wo", av)
+                x = x + g_a[:, None, :] * a
+            with jax.named_scope("mlp"):
+                h = dit_mod._modulate(dit_mod._ln(x), sh_m, sc_m)
+                hmid = jax.nn.gelu(linear(f"{bk}.wi", h))
+                x = x + g_m[:, None, :] * linear(f"{bk}.wd", hmid)
 
     modf = _cond_dense(params["final_mod"], c_act)
     shift, scl = jnp.split(modf, 2, axis=-1)
@@ -190,6 +197,12 @@ def make_step_fn(cfg: dit_mod.DiTCfg, modes: dict[str, str], plan: DittoPlan | N
     lowering, so a multi-segment :class:`PlanSchedule` is rejected here
     (a constant schedule collapses to its bare plan) — ``make_denoise_fn``
     partitions the step loop by segment and builds one step per sig.
+
+    ``state[TILE_TOTALS]`` (``compiled.TILE_TOTALS``) is the running
+    (zero, low, full) tile count per layer, rows in sorted layer order:
+    the step adds each diff layer's ``tile_hist`` to it on the device,
+    whatever ``collect_stats`` says, and passes it through unchanged when
+    no layer runs in diff mode.
     """
     plan = segment_resolved(plan_from_kwargs(
         "core.ditto.make_step_fn", plan, block=block, interpret=interpret,
@@ -221,6 +234,12 @@ def make_step_fn(cfg: dit_mod.DiTCfg, modes: dict[str, str], plan: DittoPlan | N
             return y
 
         out = _dit_forward(mparams, cfg, lin, attn, latents, t, labels)
+        totals = state[TILE_TOTALS]
+        if any("tile_hist" in a for a in aux.values()):
+            zero = jnp.zeros((3,), jnp.int32)
+            totals = totals + jnp.stack([aux[name].get("tile_hist", zero)
+                                         for name in sorted(modes)])
+        new_state[TILE_TOTALS] = totals
         return constrain_batch(out, msig), new_state, aux
 
     return step
@@ -263,7 +282,8 @@ class CompiledDittoDiT:
         out, self.state, aux = self._step(self.ceng.params, self.params, self.state,
                                           latents, t, labels)
         if self.ceng.collect_stats:
-            self.engine.record_compiled_step(aux)
+            with span("ditto.record_step", step=self.engine.step_idx):
+                self.engine.record_compiled_step(aux)
         return out
 
 
@@ -345,7 +365,8 @@ def make_denoise_fn(params, cfg: dit_mod.DiTCfg, engine: DittoEngine,
         out, cur.state, aux = box["reanchor_fn"](
             cur.ceng.params, params, cur.state, x, t, labels)
         if cur.ceng.collect_stats:
-            engine.record_compiled_step(aux, modes=act_modes, reanchor=True)
+            with span("ditto.record_step", step=engine.step_idx):
+                engine.record_compiled_step(aux, modes=act_modes, reanchor=True)
         engine.watchdog_events.append(
             {"step": engine.step_idx, "trigger": trigger, **extra})
         return out
@@ -372,7 +393,7 @@ def make_denoise_fn(params, cfg: dit_mod.DiTCfg, engine: DittoEngine,
             # (quantization clips them to an integer), so output poisoning
             # is the faithful stand-in for an fp32-side corruption
             out = faults_mod.corrupt(fault, out)
-        if not bool(jnp.isfinite(out).all()):
+        if not engine.host_read(jnp.isfinite(out).all(), bool):
             # roll back the poisoned step (state AND its records) and
             # re-run it re-anchored from the pre-step temporal state,
             # with the UN-corrupted input
@@ -388,34 +409,46 @@ def make_denoise_fn(params, cfg: dit_mod.DiTCfg, engine: DittoEngine,
                 box["reanchor_due"] = full / total
         return out
 
-    def fn(x, t, labels):
-        if plan.compiled and engine.ready_for_compiled():
-            # engine.step_idx is the current sampler step (end_step() below
-            # advances it; both samplers call this fn once per step)
-            seg_plan = (schedule.plan_for(engine.step_idx) if schedule is not None
-                        else plan)
-            sig = seg_plan.cache_sig()
-            if box.get("built_for") is not engine.records:  # rebuilt per begin_sample
+    def compiled_step(x, t, labels):
+        # engine.step_idx is the current sampler step (end_step() advances
+        # it; both samplers call fn once per step)
+        seg_plan = (schedule.plan_for(engine.step_idx) if schedule is not None
+                    else plan)
+        sig = seg_plan.cache_sig()
+        if box.get("built_for") is not engine.records:  # rebuilt per begin_sample
+            with span("ditto.runner_build"):
                 box["runner"] = CompiledDittoDiT(params, cfg, engine, seg_plan,
                                                  cache=runner_cache, bucket=bucket)
-                box["built_for"] = engine.records
-                box["sig"] = sig
-                box.pop("reanchor_due", None)  # saturation never crosses samples
-            elif box["sig"] != sig:  # segment boundary: swap lowering, carry state
-                prev = box["runner"]
+            box["built_for"] = engine.records
+            box["sig"] = sig
+            box.pop("reanchor_due", None)  # saturation never crosses samples
+        elif box["sig"] != sig:  # segment boundary: swap lowering, carry state
+            prev = box["runner"]
+            with span("ditto.runner_build"):
                 box["runner"] = CompiledDittoDiT(params, cfg, engine, seg_plan,
                                                  cache=runner_cache, bucket=bucket)
-                box["runner"].state = prev.state
-                box["sig"] = sig
-            if watchdog:
-                out = guarded_step(x, t, labels)
-                if not bool(jnp.isfinite(out).all()):
-                    raise faults_mod.NumericalFault(engine.step_idx)
-            else:
-                out = box["runner"](x, t, labels)
+            box["runner"].state = prev.state
+            box["sig"] = sig
+        if watchdog:
+            out = guarded_step(x, t, labels)
+            if not engine.host_read(jnp.isfinite(out).all(), bool):
+                raise faults_mod.NumericalFault(engine.step_idx)
         else:
-            out = runner(x, t, labels)
-        engine.end_step()
+            out = box["runner"](x, t, labels)
+        engine.tile_totals = box["runner"].state[TILE_TOTALS]
+        return out
+
+    def fn(x, t, labels):
+        compiled = plan.compiled and engine.ready_for_compiled()
+        with span("ditto.compiled_step" if compiled else "ditto.eager_step",
+                  step=engine.step_idx):
+            if compiled:
+                out = compiled_step(x, t, labels)
+                engine.compiled_steps += 1
+            else:
+                out = runner(x, t, labels)
+                engine.eager_steps += 1
+            engine.end_step()
         return out
 
     return fn

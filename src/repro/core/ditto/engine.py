@@ -72,6 +72,7 @@ class DittoEngine:
         self._decided = False
         self._compiled_base = None  # cached (modes, first-record-per-layer)
         self.watchdog_events: list[dict] = []  # re-anchor events (serve watchdog)
+        self._reset_counters()
 
     # ------------------------------------------------------------- weights
     def register_linear(self, meta: LayerMeta, w: jax.Array, bias: jax.Array | None = None):
@@ -90,11 +91,41 @@ class DittoEngine:
         self._compiled_base = None
         self.records = []
         self.watchdog_events = []
+        self._reset_counters()
         for st in self.layers.values():
             st.x_prev = st.y_prev = None
             st.a_prev = st.b_prev = None
             st.x_scale = st.a_scale = st.b_scale = None
             st.mode = "act"
+
+    # ------------------------------------------------------------ counters
+    def _reset_counters(self):
+        self.host_reads = 0  # blocking device->host reads of this sample
+        self.eager_steps = 0
+        self.compiled_steps = 0
+        # device (layers, 3) int32 (zero, low, full) tile totals of the
+        # compiled steps, rows in sorted layer order (make_step_fn)
+        self.tile_totals = None
+
+    def host_read(self, v, cast=float):
+        """``cast(v)``: one blocking device->host read, counted."""
+        self.host_reads += 1
+        return cast(v)
+
+    def counters(self) -> dict:
+        """This sample's counters. ``tile_hist`` maps each diff-mode layer
+        to its (zero, low, full) tile counts summed over the compiled
+        steps, fetched with one ``device_get`` (not counted in
+        ``host_reads``)."""
+        tiles: dict[str, tuple] = {}
+        if self.tile_totals is not None:
+            modes = self.compiled_modes()
+            rows = np.asarray(jax.device_get(self.tile_totals))
+            tiles = {name: tuple(int(v) for v in row)
+                     for name, row in zip(sorted(self.layers), rows)
+                     if modes[name] == "diff"}
+        return {"host_reads": self.host_reads, "eager_steps": self.eager_steps,
+                "compiled_steps": self.compiled_steps, "tile_hist": tiles}
 
     def end_step(self):
         self.step_idx += 1
@@ -234,12 +265,13 @@ class DittoEngine:
     def _account(self, rec, t, k, n, q_t, d, meta, *, attention=False):
         # --- class fractions, per candidate mode (the simulator re-prices
         # each hardware design from these; see repro.sim) ---
+        read = self.host_read
         q_cls = classify.element_classes(q_t)
-        cls_act = (float(q_cls["zero"]), 0.0, float(q_cls["low"] + q_cls["full"]))
+        cls_act = (read(q_cls["zero"]), 0.0, read(q_cls["low"] + q_cls["full"]))
         cls_diff = None
         if d is not None:
             cls = classify.element_classes(d)
-            cls_diff = (float(cls["zero"]), float(cls["low"]), float(cls["full"]))
+            cls_diff = (read(cls["zero"]), read(cls["low"]), read(cls["full"]))
         self._account_classes(rec, t, k, n, cls_act, cls_diff, meta, attention=attention)
         hw = self.hw
         macs = rec["macs"]
@@ -250,7 +282,7 @@ class DittoEngine:
             if q2 is not None and t > 1:
                 ds = classify.spatial_diff(q2, axis=0)[1:]
                 cs = classify.element_classes(ds)
-                z2, l2, f2 = float(cs["zero"]), float(cs["low"]), float(cs["full"])
+                z2, l2, f2 = read(cs["zero"]), read(cs["low"]), read(cs["full"])
                 # the first row stays full precision
                 w0 = 1.0 / t
                 rec["cls_spatial"] = (z2 * (1 - w0), l2 * (1 - w0), f2 * (1 - w0) + w0)
@@ -375,13 +407,14 @@ class DittoEngine:
                                    "kind": meta.kind, "macs": base["macs"], "compiled": True}
             if reanchor:
                 rec["reanchor"] = True
-            cls_act = tuple(float(v) for v in a["cls_act"])
-            cls_diff = tuple(float(v) for v in a["cls_diff"]) if "cls_diff" in a else None
-            cls_sp = tuple(float(v) for v in a["cls_spatial"]) if "cls_spatial" in a else None
+            read = self.host_read
+            cls_act = tuple(read(v) for v in a["cls_act"])
+            cls_diff = tuple(read(v) for v in a["cls_diff"]) if "cls_diff" in a else None
+            cls_sp = tuple(read(v) for v in a["cls_spatial"]) if "cls_spatial" in a else None
             self._account_classes(rec, base["t"], base["k"], base["n"], cls_act, cls_diff, meta,
                                   attention=base["attention"], cls_spatial=cls_sp)
             if "tile_hist" in a:
-                hist = tuple(int(v) for v in a["tile_hist"])
+                hist = tuple(read(v, int) for v in a["tile_hist"])
                 rec["tile_hist"] = hist
                 rec["tile_fracs"] = bops_mod.tile_fractions(hist)
                 rec["bops_tile"] = bops_mod.bops_tile_mix(rec["macs"], hist)

@@ -166,6 +166,9 @@ class Ticket:
         self.plan = plan  # normalized plan/schedule this request runs under
         self.deadline_ms = deadline_ms  # latency budget; None = no SLO
         self.submit_t = submit_t  # scheduler-clock time of submit()
+        # scheduler-clock time its first rows left the queue for a dispatch;
+        # queue wait = dispatch_t - submit_t
+        self.dispatch_t: float | None = None
         self.done_t: float | None = None  # scheduler-clock time of completion
         # absolute budget expiry on the scheduler clock; the dispatch policy
         # compares against this, never against wall time directly
@@ -385,6 +388,8 @@ class ServeScheduler:
         self._retries = 0
         self._fallbacks = 0
         self._shed = 0
+        self._queue_wait_s = 0.0  # sum of dispatch_t - submit_t
+        self._tickets_dispatched = 0  # tickets whose first rows were taken
         self._died: BaseException | None = None
         self._triggers = {"full": 0, "deadline": 0, "demand": 0, "drain": 0,
                           "steal": 0}
@@ -889,8 +894,13 @@ class ServeScheduler:
             self._cv.notify_all()
             raise _TakeFailed(str(exc)) from exc
         segments = []
+        now = self._clock()
         for p, c in plan_items:
             segments.append((p.ticket, p.used, c))
+            if p.ticket.dispatch_t is None:
+                p.ticket.dispatch_t = now
+                self._queue_wait_s += now - p.ticket.submit_t
+                self._tickets_dispatched += 1
             p.used += c
         while group.pending and not group.pending[0].remaining:
             group.pending.popleft()
@@ -1035,6 +1045,8 @@ class ServeScheduler:
                     "retries": self._retries,
                     "fallback_dispatches": self._fallbacks,
                     "shed": self._shed,
+                    "queue_wait_s": self._queue_wait_s,
+                    "tickets_dispatched": self._tickets_dispatched,
                     "died": self._died is not None}
             if self.mesh is None:
                 out.update(self.session.stats())
@@ -1048,6 +1060,10 @@ class ServeScheduler:
                                            + s.requests_served)
                         out["watchdog_events"] = (out.get("watchdog_events", 0)
                                                   + s.watchdog_events)
+                        for k, v in s.counters.items():
+                            out[k] = out.get(k, 0) + v
+                        out["tiles"] = [a + b for a, b in
+                                        zip(out.get("tiles", (0, 0, 0)), s.tiles)]
                 cache = getattr(self.session, "cache", None)
                 if cache is not None:
                     out.update(cache.stats())

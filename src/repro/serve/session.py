@@ -21,22 +21,31 @@ trace + compile, every later batch replays the cached runner.
 
 The pre-plan constructor keywords (``steps=``, ``low_bits=``, ...) are a
 deprecated shim that builds the equivalent plan and warns once.
+
+Each call is one ``serve.dispatch`` host span, and each chunk reports the
+engine's counters (``ChunkResult.counters``; see docs/architecture.md,
+"Observability"), summed into :meth:`ServeSession.stats`.
 """
 from __future__ import annotations
 
 import dataclasses
+import itertools
 import threading
 import time
 from typing import Any
 
 import jax
 
+from ..core import spans
 from ..core.ditto.plan import (UNSET, DittoPlan, PlanSchedule, plan_from_kwargs,
                                require_native_lowering)
 from ..sim import harness
 from . import faults
 from .bucketing import bucket_for
 from .cache import CompiledRunnerCache
+
+#: scalar counters a served chunk reports and ``ServeSession.stats()`` sums
+STEP_COUNTERS = ("host_reads", "eager_steps", "compiled_steps")
 
 
 @dataclasses.dataclass
@@ -49,6 +58,9 @@ class ChunkResult:
     bucket: int | None  # padded dispatch size; None = eager (unbucketed) chunk
     wall_s: float
     traces_delta: int  # new XLA traces this chunk caused (0 = full cache hit)
+    # the engine's counters (DittoEngine.counters): host_reads, eager_steps,
+    # compiled_steps, and tile_hist {diff layer: (zero, low, full)}
+    counters: dict = dataclasses.field(default_factory=dict)
 
     @property
     def pad_rows(self) -> int:
@@ -76,6 +88,19 @@ class ServeResult:
     @property
     def pad_rows(self) -> int:
         return sum(c.pad_rows for c in self.chunks)
+
+    @property
+    def counters(self) -> dict:
+        """The chunks' counters summed; ``tile_hist`` per layer."""
+        out = dict.fromkeys(STEP_COUNTERS, 0)
+        tiles: dict[str, tuple] = {}
+        for c in self.chunks:
+            for k in STEP_COUNTERS:
+                out[k] += c.counters.get(k, 0)
+            for name, h in c.counters.get("tile_hist", {}).items():
+                tiles[name] = tuple(a + b for a, b in zip(tiles.get(name, (0, 0, 0)), h))
+        out["tile_hist"] = tiles
+        return out
 
 
 class ServeSession:
@@ -125,6 +150,9 @@ class ServeSession:
         self.batches_served = 0
         self.requests_served = 0
         self.watchdog_events = 0  # re-anchor steps across all served chunks
+        self.counters = dict.fromkeys(STEP_COUNTERS, 0)
+        self.tiles = [0, 0, 0]  # (zero, low, full) diff tiles, all layers
+        self._dispatch_ids = itertools.count()  # serve.dispatch span index
         # sessions are documented as shareable across request threads (one
         # shared cache); bare += on the counters would drop increments
         self._stats_lock = threading.Lock()
@@ -146,20 +174,28 @@ class ServeSession:
         n = x.shape[0]
         chunks: list[ChunkResult] = []
         samples = []
-        for lo in range(0, n, plan.max_batch):
-            hi = min(lo + plan.max_batch, n)
-            xc = x[lo:hi]
-            lc = None if labels is None else labels[lo:hi]
-            chunks.append(self._serve_chunk(xc, lc, plan))
-            samples.append(chunks[-1].sample)
+        bounds = [(lo, min(lo + plan.max_batch, n)) for lo in range(0, n, plan.max_batch)]
+        buckets = ",".join(str(bucket_for(hi - lo, max_batch=plan.max_batch)
+                               if plan.compiled else hi - lo) for lo, hi in bounds)
+        with spans.dispatch(next(self._dispatch_ids), rows=n, buckets=buckets):
+            for lo, hi in bounds:
+                lc = None if labels is None else labels[lo:hi]
+                chunks.append(self._serve_chunk(x[lo:hi], lc, plan))
+                samples.append(chunks[-1].sample)
         events = sum(
             len(getattr(c.engine, "watchdog_events", ()) or ()) for c in chunks)
+        sample = samples[0] if len(samples) == 1 else jax.numpy.concatenate(samples, axis=0)
+        result = ServeResult(sample=sample, chunks=chunks)
+        counters = result.counters
         with self._stats_lock:
             self.batches_served += 1
             self.requests_served += n
             self.watchdog_events += events
-        sample = samples[0] if len(samples) == 1 else jax.numpy.concatenate(samples, axis=0)
-        return ServeResult(sample=sample, chunks=chunks)
+            for k in STEP_COUNTERS:
+                self.counters[k] += counters[k]
+            for h in counters["tile_hist"].values():
+                self.tiles = [a + b for a, b in zip(self.tiles, h)]
+        return result
 
     def _serve_chunk(self, x, labels, plan: DittoPlan | PlanSchedule) -> ChunkResult:
         b = x.shape[0]
@@ -178,13 +214,17 @@ class ServeSession:
                 self.params, self.cfg, self.sched, x, labels, plan,
                 runner_cache=self.cache, bucket=bucket, **mesh_kw,
             )
-            jax.block_until_ready(sample)
+            with spans.span("serve.block"):  # the host waiting on the device
+                jax.block_until_ready(sample)
         wall = time.monotonic() - t0
+        counters = eng.counters() if hasattr(eng, "counters") else {}
         return ChunkResult(sample=sample, records=records, engine=eng, batch=b,
-                           bucket=bucket, wall_s=wall, traces_delta=att.count)
+                           bucket=bucket, wall_s=wall, traces_delta=att.count,
+                           counters=counters)
 
     # ---------------------------------------------------------------- stats
     def stats(self) -> dict:
-        return {"batches": self.batches_served, "requests": self.requests_served,
-                "watchdog_events": self.watchdog_events,
-                **self.cache.stats()}
+        with self._stats_lock:
+            return {"batches": self.batches_served, "requests": self.requests_served,
+                    "watchdog_events": self.watchdog_events, **self.counters,
+                    "tiles": list(self.tiles), **self.cache.stats()}

@@ -10,6 +10,7 @@ from __future__ import annotations
 import jax
 
 from ..core import diffusion
+from ..core.spans import span
 from ..core.ditto import CAMBRICON_D, DIFFY, DITTO_HW, ITC, DittoEngine, make_denoise_fn
 from ..core.ditto.plan import UNSET, DittoPlan, PlanSchedule, plan_from_kwargs
 from ..nn import dit as dit_mod
@@ -97,9 +98,10 @@ def serve_records(params, cfg: dit_mod.DiTCfg, sched, x_T, labels=None,
 
         mesh = mesh_mod.resolve_mesh(plan, mesh)
         x_T, labels = mesh_mod.place_dispatch(x_T, labels, mesh, plan.mesh_axis)
-    eng = DittoEngine(policy=plan.policy, collect_oracle=plan.collect_stats)
-    fn = make_denoise_fn(params, cfg, eng, plan, runner_cache=runner_cache,
-                         bucket=x_T.shape[0])
+    with span("ditto.requantize"):  # every weight pulled and quantized
+        eng = DittoEngine(policy=plan.policy, collect_oracle=plan.collect_stats)
+        fn = make_denoise_fn(params, cfg, eng, plan, runner_cache=runner_cache,
+                             bucket=x_T.shape[0])
     eng.begin_sample()
     sample = diffusion.SAMPLERS[plan.sampler](sched, fn, x_T, steps=plan.steps,
                                               labels=labels)

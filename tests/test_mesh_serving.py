@@ -151,6 +151,8 @@ class _ShardSession:
         self.batches_served = 0
         self.requests_served = 0
         self.watchdog_events = 0
+        self.counters = {"host_reads": 0, "eager_steps": 0, "compiled_steps": 0}
+        self.tiles = [0, 0, 0]
         self._stats_lock = threading.Lock()
 
     def serve(self, x, labels, plan=None):
