@@ -42,15 +42,21 @@ two sub-operations through the same diff kernel under ``lax.scan`` over
 the (batch x heads) leading dim (one kernel trace, not one per element).
 
 With ``collect_stats=True`` the step also reduces zero/low/full class
-fractions on-device and returns them as an aux pytree; the host engine
+fractions on-device into a per-layer aux pytree; the host engine
 synthesizes cost-model records from them (``record_compiled_step``) so the
 design-point simulator keeps working across compiled steps. Set it False
 for the pure serving fast path. Either way each diff layer's measured
 tile-class histogram (``tile_hist``) is in the aux pytree, and the step
 adds it to the state's :data:`TILE_TOTALS` leaf, a device-side counter the
-host reads once per sample.
+host reads once per sample. The step returns its aux packed
+(:func:`pack_stats`): one float32 vector of every class fraction and one
+int32 vector of every ``tile_hist`` count, laid out by the aux pytree's own
+flattened structure, so the host reads a step's statistics with one
+transfer instead of one per scalar.
 """
 from __future__ import annotations
+
+import math
 
 import jax
 import jax.numpy as jnp
@@ -63,6 +69,56 @@ from .plan import UNSET, DittoPlan, plan_from_kwargs, segment_resolved
 #: state key of the (layers, 3) int32 running (zero, low, full) tile totals,
 #: one row per layer in sorted name order
 TILE_TOTALS = "tile_totals"
+
+
+@jax.tree_util.register_pytree_node_class
+class PackedStats:
+    """One compiled step's per-layer aux pytree in two flat arrays.
+
+    ``fracs`` (float32) holds every floating leaf — the class fractions —
+    and ``tiles`` (int32) every integer leaf — the ``tile_hist`` counts —
+    each in the aux pytree's flattened order. ``layout`` is that pytree's
+    treedef and each leaf's (integer?, shape): static pytree data, so it
+    leaves ``jax.jit`` with the arrays and always describes the step that
+    produced them. :meth:`unpack` rebuilds the aux pytree, with the same
+    values, from the host copy that ``jax.device_get`` returns."""
+
+    def __init__(self, fracs, tiles, layout):
+        self.fracs, self.tiles, self.layout = fracs, tiles, layout
+
+    def tree_flatten(self):
+        return (self.fracs, self.tiles), self.layout
+
+    @classmethod
+    def tree_unflatten(cls, layout, children):
+        return cls(*children, layout)
+
+    def unpack(self) -> dict:
+        treedef, specs = self.layout
+        offsets = {False: 0, True: 0}
+        leaves = []
+        for is_int, shape in specs:
+            vec = self.tiles if is_int else self.fracs
+            off, size = offsets[is_int], math.prod(shape)
+            leaves.append(vec[off:off + size].reshape(shape))
+            offsets[is_int] = off + size
+        return jax.tree_util.tree_unflatten(treedef, leaves)
+
+
+def pack_stats(aux: dict) -> PackedStats:
+    """Pack a step's aux pytree into a :class:`PackedStats` (inside the
+    jitted step). Exact: every fraction is a float32 scalar and every
+    tile count an int32."""
+    leaves, treedef = jax.tree_util.tree_flatten(aux)
+    leaves = [jnp.asarray(v) for v in leaves]
+    is_int = [bool(jnp.issubdtype(v.dtype, jnp.integer)) for v in leaves]
+
+    def flat(kind, dtype):
+        parts = [v.reshape(-1).astype(dtype) for v, i in zip(leaves, is_int) if i == kind]
+        return jnp.concatenate(parts) if parts else jnp.zeros((0,), dtype)
+
+    layout = (treedef, tuple((i, v.shape) for v, i in zip(leaves, is_int)))
+    return PackedStats(flat(False, jnp.float32), flat(True, jnp.int32), layout)
 
 
 def _class_fractions(d: jax.Array) -> tuple:

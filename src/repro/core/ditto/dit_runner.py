@@ -177,11 +177,13 @@ def make_step_fn(cfg: dit_mod.DiTCfg, modes: dict[str, str], plan: DittoPlan | N
     """Build the pure per-step function of the compiled execution pass.
 
     Returns ``step(ditto_params, model_params, state, latents, t, labels)
-    -> (eps_hat, new_state, aux)``. Everything data-dependent — the
-    per-layer Ditto params (weight q-tensors, calibrated scales, biases),
-    the fp32 model params for the VPU-side glue, and the temporal state —
-    is an ARGUMENT, so the only trace-static inputs are ``cfg``, the
-    frozen per-layer ``modes``, and the plan's trace identity
+    -> (eps_hat, new_state, stats)``, where ``stats`` is the per-layer aux
+    pytree packed into two arrays (:class:`compiled.PackedStats`).
+    Everything data-dependent — the per-layer Ditto params (weight
+    q-tensors, calibrated scales, biases), the fp32 model params for the
+    VPU-side glue, and the temporal state — is an ARGUMENT, so the only
+    trace-static inputs are ``cfg``, the frozen per-layer ``modes``, and
+    the plan's trace identity
     (``plan.cache_sig()``: block / interpret / collect_stats / low_bits /
     fused). Two serve batches that share those statics (and
     shapes) can therefore share ONE ``jax.jit`` trace: this is what
@@ -240,7 +242,7 @@ def make_step_fn(cfg: dit_mod.DiTCfg, modes: dict[str, str], plan: DittoPlan | N
             totals = totals + jnp.stack([aux[name].get("tile_hist", zero)
                                          for name in sorted(modes)])
         new_state[TILE_TOTALS] = totals
-        return constrain_batch(out, msig), new_state, aux
+        return constrain_batch(out, msig), new_state, compiled_mod.pack_stats(aux)
 
     return step
 
@@ -250,8 +252,8 @@ class CompiledDittoDiT:
     denoiser, built from a calibrated engine. Per-layer temporal state
     (x_prev/y_prev/attention operands) is threaded functionally; modes are
     frozen at trace time. With collect_stats, on-device class fractions
-    come back as an aux pytree and the engine synthesizes cost-model
-    records for the step.
+    come back packed with the tile histograms, are read in one transfer,
+    and the engine synthesizes cost-model records for the step.
 
     With ``cache`` (a :class:`repro.serve.CompiledRunnerCache`) the jitted
     step is fetched from / registered in the cache instead of being jitted
@@ -279,11 +281,11 @@ class CompiledDittoDiT:
             self._step = jax.jit(make_step_fn(cfg, self.ceng.modes, plan))
 
     def __call__(self, latents, t, labels=None):
-        out, self.state, aux = self._step(self.ceng.params, self.params, self.state,
-                                          latents, t, labels)
+        out, self.state, stats = self._step(self.ceng.params, self.params, self.state,
+                                            latents, t, labels)
         if self.ceng.collect_stats:
             with span("ditto.record_step", step=self.engine.step_idx):
-                self.engine.record_compiled_step(aux)
+                self.engine.record_compiled_step(stats)
         return out
 
 
@@ -362,11 +364,11 @@ def make_denoise_fn(params, cfg: dit_mod.DiTCfg, engine: DittoEngine,
             else:
                 box["reanchor_fn"] = jax.jit(make_step_fn(cfg, act_modes, rplan))
             box["reanchor_sig"] = rsig
-        out, cur.state, aux = box["reanchor_fn"](
+        out, cur.state, stats = box["reanchor_fn"](
             cur.ceng.params, params, cur.state, x, t, labels)
         if cur.ceng.collect_stats:
             with span("ditto.record_step", step=engine.step_idx):
-                engine.record_compiled_step(aux, modes=act_modes, reanchor=True)
+                engine.record_compiled_step(stats, modes=act_modes, reanchor=True)
         engine.watchdog_events.append(
             {"step": engine.step_idx, "trigger": trigger, **extra})
         return out

@@ -108,7 +108,9 @@ class DittoEngine:
         self.tile_totals = None
 
     def host_read(self, v, cast=float):
-        """``cast(v)``: one blocking device->host read, counted."""
+        """``cast(v)``: one blocking device->host read, counted. The eager
+        step reads its class fractions one scalar at a time; a compiled
+        step's packed statistics are one read (``cast=jax.device_get``)."""
         self.host_reads += 1
         return cast(v)
 
@@ -372,21 +374,24 @@ class DittoEngine:
             modes[name] = m
         return modes
 
-    def record_compiled_step(self, aux: dict[str, dict], *,
-                             modes: dict[str, str] | None = None,
+    def record_compiled_step(self, stats, *, modes: dict[str, str] | None = None,
                              reanchor: bool = False) -> None:
         """Append records for one compiled step.
 
-        ``aux`` comes out of the jitted step function: per layer, the
-        zero/low/full class fractions reduced on-device — 'cls_act'
-        always, 'cls_diff' / 'cls_spatial' where the layer has the state
-        to measure them (candidate stats are kept even for act-frozen
-        layers so the simulator can re-price other designs' mode choices).
-        Diff-mode layers additionally carry 'tile_hist', the measured
-        (n_zero, n_low, n_full) tile-class histogram from ``diff_encode``
-        — the tiles the kernel REALLY skipped / routed through the
-        packed-int4 branch; it lands on the record together with its
-        tile-granular pricing ('bops_tile', 'tile_fracs').
+        ``stats`` (a ``compiled.PackedStats``) comes out of the jitted step
+        function: the step's per-layer aux pytree packed into one float32
+        vector of class fractions and one int32 vector of tile counts,
+        with the aux pytree's layout as static data. It is fetched with one
+        ``jax.device_get`` (one counted host read) and unpacked on the host
+        into, per layer, the zero/low/full class fractions reduced
+        on-device — 'cls_act' always, 'cls_diff' / 'cls_spatial' where the
+        layer has the state to measure them (candidate stats are kept even
+        for act-frozen layers so the simulator can re-price other designs'
+        mode choices). Diff-mode layers additionally carry 'tile_hist', the
+        measured (n_zero, n_low, n_full) tile-class histogram from
+        ``diff_encode`` — the tiles the kernel REALLY skipped / routed
+        through the packed-int4 branch; it lands on the record together
+        with its tile-granular pricing ('bops_tile', 'tile_fracs').
         Layer dimensions are reused from that layer's calibration-step
         record — shapes are static across the denoising loop (same
         latents/batch), which is exactly what lets the step be jitted in
@@ -400,6 +405,7 @@ class DittoEngine:
         base_modes, base_by_layer = self._compiled_base
         if modes is None:
             modes = base_modes
+        aux = self.host_read(stats, jax.device_get).unpack()
         for name, a in aux.items():
             base = base_by_layer[name]
             meta = self.meta[name]
@@ -407,14 +413,13 @@ class DittoEngine:
                                    "kind": meta.kind, "macs": base["macs"], "compiled": True}
             if reanchor:
                 rec["reanchor"] = True
-            read = self.host_read
-            cls_act = tuple(read(v) for v in a["cls_act"])
-            cls_diff = tuple(read(v) for v in a["cls_diff"]) if "cls_diff" in a else None
-            cls_sp = tuple(read(v) for v in a["cls_spatial"]) if "cls_spatial" in a else None
+            cls_act = tuple(float(v) for v in a["cls_act"])
+            cls_diff = tuple(float(v) for v in a["cls_diff"]) if "cls_diff" in a else None
+            cls_sp = tuple(float(v) for v in a["cls_spatial"]) if "cls_spatial" in a else None
             self._account_classes(rec, base["t"], base["k"], base["n"], cls_act, cls_diff, meta,
                                   attention=base["attention"], cls_spatial=cls_sp)
             if "tile_hist" in a:
-                hist = tuple(read(v, int) for v in a["tile_hist"])
+                hist = tuple(int(v) for v in a["tile_hist"])
                 rec["tile_hist"] = hist
                 rec["tile_fracs"] = bops_mod.tile_fractions(hist)
                 rec["bops_tile"] = bops_mod.bops_tile_mix(rec["macs"], hist)

@@ -4,9 +4,10 @@
   * spans: a toy-width serve under ``jax.profiler.trace`` writes the
     ``serve.*``/``ditto.*``/``diffusion.*`` host spans, one per phase and
     step, each carrying the dispatch index;
-  * counters: ``host_reads``, ``eager_steps``, ``compiled_steps`` and the
-    per-layer ``tile_hist`` (accumulated on the device, read once per
-    dispatch) with ``collect_stats`` on and off, summed into
+  * counters: ``host_reads`` (one per compiled step's packed statistics),
+    ``eager_steps``, ``compiled_steps`` and the per-layer ``tile_hist``
+    (accumulated on the device, read once per dispatch) with
+    ``collect_stats`` on and off, summed into
     ``ServeSession.stats()`` and ``ServeScheduler.stats()``;
   * tickets stamp ``dispatch_t``; the scheduler sums the queue wait;
   * the jitted step's device ops carry their block's named scope.
@@ -91,16 +92,23 @@ def test_step_counters_cover_every_step(served, stats):
     assert c["host_reads"] > 0  # the eager step's class fractions are read
 
 
-def test_host_reads_are_counted_per_scalar_and_fall_with_stats_off(served):
+def test_host_reads_are_one_per_compiled_step_and_fall_with_stats_off(served):
     on, off = (served[s][2].counters["host_reads"] for s in (True, False))
     assert on > off
-    compiled = [r for r in served[True][2].records if r.get("compiled")]
-    # each compiled record reads its class triples and, on diff layers, the
-    # tile histogram, one scalar at a time
-    per_step = sum(3 * sum(k in r for k in ("cls_act", "cls_diff", "cls_spatial", "tile_hist"))
-                   for r in compiled)
-    assert on - off == per_step + 3 * sum(  # the eager step's spatial oracle
-        1 for r in served[True][2].records if "cls_spatial" in r and not r.get("compiled"))
+    records = served[True][2].records
+    compiled_steps = served[True][2].counters["compiled_steps"]
+    assert compiled_steps == len({r["step"] for r in records if r.get("compiled")})
+    # each compiled step's statistics are one packed fetch; the eager step
+    # adds its spatial oracle, three scalars per record that carries one
+    assert on - off == compiled_steps + 3 * sum(
+        1 for r in records if "cls_spatial" in r and not r.get("compiled"))
+    # the jitted step returns its statistics as at most two arrays
+    dparams, mparams, lat, t, labels = ta.abstract_inputs(CFG, 2)
+    step = dit_runner.make_step_fn(CFG, ta.uniform_modes(CFG, "diff"),
+                                   DittoPlan(collect_stats=True))
+    _, _, stats = jax.eval_shape(step, dparams, mparams, ta.abstract_state(CFG, 2),
+                                 lat, t, labels)
+    assert len(jax.tree_util.tree_leaves(stats)) <= 2
 
 
 @pytest.mark.parametrize("stats", [True, False])
