@@ -128,42 +128,76 @@ def audit_cases(cases: list[TraceCase], *, group: str = "", check_dup: bool = Tr
     return findings
 
 
-# -------------------------------------------- abstract DiT inputs (no data)
-def _layer_names(cfg):
-    linear = []
+# --------------------------------- abstract DiT / MMDiT inputs (no data)
+def _is_mmdit(cfg) -> bool:
+    from repro.nn.mmdit import MMDiTCfg
+
+    return isinstance(cfg, MMDiTCfg)
+
+
+def _mmdit_linear_dims(cfg, batch: int) -> dict:
+    """``{call site: (k, n, rows)}`` of every MMDiT engine linear."""
+    from repro.nn import mmdit as mmdit_mod
+
+    d, hid = cfg.d_model, cfg.ff
+    out = {}
     for i in range(cfg.n_layers):
-        b = f"blk{i}"
-        linear += [f"{b}.mod", f"{b}.wq", f"{b}.wk", f"{b}.wv", f"{b}.wo",
-                   f"{b}.wi", f"{b}.wd"]
-    linear.append("final.out")
+        for s, tok in (("img", cfg.n_tokens), ("txt", cfg.ctx_tokens)):
+            pre_only = s == "txt" and i == cfg.n_layers - 1
+            rows = batch * tok
+            dims = {"mod": (d, (2 if pre_only else 6) * d, batch), "wq": (d, d, rows),
+                    "wk": (d, d, rows), "wv": (d, d, rows), "wo": (d, d, rows),
+                    "wi": (d, hid, rows), "wd": (hid, d, rows)}
+            names = mmdit_mod.PRE_ONLY_LINEARS if pre_only else mmdit_mod.STREAM_LINEARS
+            out.update({f"blk{i}.{s}.{nm}": dims[nm] for nm in names})
+    out["final.out"] = (d, cfg.patch_dim, batch * cfg.n_tokens)
+    return out
+
+
+def _layer_names(cfg):
+    if _is_mmdit(cfg):
+        linear = list(_mmdit_linear_dims(cfg, 1))
+    else:
+        linear = []
+        for i in range(cfg.n_layers):
+            b = f"blk{i}"
+            linear += [f"{b}.mod", f"{b}.wq", f"{b}.wk", f"{b}.wv", f"{b}.wo",
+                       f"{b}.wi", f"{b}.wd"]
+        linear.append("final.out")
     attn = [f"blk{i}.{s}" for i in range(cfg.n_layers) for s in ("qk", "pv")]
     return linear, attn
 
 
 def abstract_inputs(cfg, batch: int):
     """ShapeDtypeStruct pytrees for one step: (dparams, mparams, latents,
-    t, labels). Weight values never exist — ``init`` runs under
-    ``eval_shape`` and the per-layer Ditto params are written directly as
-    shape structs mirroring what ``DittoEngine.register_*`` produces."""
+    t, labels), for a ``DiTCfg`` or an ``MMDiTCfg``. Weight values never
+    exist — ``init`` runs under ``eval_shape`` and the per-layer Ditto
+    params are written directly as shape structs mirroring what
+    ``DittoEngine.register_*`` produces."""
     import jax
     import jax.numpy as jnp
 
     from repro.nn import dit as dit_mod
+    from repro.nn import mmdit as mmdit_mod
 
     S = jax.ShapeDtypeStruct
-    mparams = jax.eval_shape(lambda k: dit_mod.init(k, cfg), jax.random.PRNGKey(0))
-    d, tok, hid = cfg.d_model, cfg.n_tokens, int(cfg.mlp_ratio * cfg.d_model)
-    rows_tok = batch * tok
-    dims = {"mod": (d, 6 * d, batch), "wq": (d, d, rows_tok), "wk": (d, d, rows_tok),
-            "wv": (d, d, rows_tok), "wo": (d, d, rows_tok), "wi": (d, hid, rows_tok),
-            "wd": (hid, d, rows_tok), "out": (d, cfg.patch_dim, rows_tok)}
+    model = mmdit_mod if _is_mmdit(cfg) else dit_mod
+    mparams = jax.eval_shape(lambda k: model.init(k, cfg), jax.random.PRNGKey(0))
 
     def lin_p(k, n, rows):
         return dict(w_q=S((k, n), jnp.int8), w_scale=S((n,), jnp.float32),
                     bias=S((n,), jnp.float32), x_scale=S((rows, 1), jnp.float32))
 
     linear, attn = _layer_names(cfg)
-    dparams = {nm: lin_p(*dims[nm.split(".")[-1]]) for nm in linear}
+    if _is_mmdit(cfg):
+        dparams = {nm: lin_p(*dims) for nm, dims in _mmdit_linear_dims(cfg, batch).items()}
+    else:
+        d, tok, hid = cfg.d_model, cfg.n_tokens, int(cfg.mlp_ratio * cfg.d_model)
+        rows_tok = batch * tok
+        dims = {"mod": (d, 6 * d, batch), "wq": (d, d, rows_tok), "wk": (d, d, rows_tok),
+                "wv": (d, d, rows_tok), "wo": (d, d, rows_tok), "wi": (d, hid, rows_tok),
+                "wd": (hid, d, rows_tok), "out": (d, cfg.patch_dim, rows_tok)}
+        dparams = {nm: lin_p(*dims[nm.split(".")[-1]]) for nm in linear}
     bh = batch * cfg.n_heads
     for nm in attn:
         dparams[nm] = dict(a_scale=S((bh, 1, 1), jnp.float32),

@@ -142,6 +142,59 @@ def dit_graph(n_layers: int) -> list[GNode]:
     return nodes
 
 
+def mmdit_graph(n_layers: int) -> list[GNode]:
+    """Op graph of one MMDiT forward (call sites named as in
+    ``dit_runner._mmdit_forward``). Each stream repeats the DiT block's
+    fences; the joint attention concatenates both streams' q/k/v
+    (diff-transparent) into ``attn_qk``/``attn_pv`` and splits the output
+    back to each stream's ``wo``. The last block's text stream is
+    ``context_pre_only``: modulate -> q/k/v, and its share of the attention
+    output goes nowhere."""
+    nodes = [GNode("img0", "input"), GNode("txt0", "input"), GNode("c_silu", "silu", ("img0",))]
+    prev = {"img": "img0", "txt": "txt0"}
+    for i in range(n_layers):
+        b = f"blk{i}"
+        pre_only = i == n_layers - 1
+        for s in ("img", "txt"):
+            p = f"{b}.{s}"
+            nodes += [
+                GNode(f"{p}.mod", "linear", ("c_silu",)),
+                GNode(f"{p}.ln1", "norm", (prev[s],)),
+                GNode(f"{p}.modulate1", "modulate", (f"{p}.ln1", f"{p}.mod")),
+                GNode(f"{p}.wq", "linear", (f"{p}.modulate1",)),
+                GNode(f"{p}.wk", "linear", (f"{p}.modulate1",)),
+                GNode(f"{p}.wv", "linear", (f"{p}.modulate1",)),
+            ]
+        nodes += [GNode(f"{b}.{x}_cat", "concat", (f"{b}.img.w{x}", f"{b}.txt.w{x}"))
+                  for x in ("q", "k", "v")]
+        nodes += [
+            GNode(f"{b}.qk", "attn_qk", (f"{b}.q_cat", f"{b}.k_cat")),
+            GNode(f"{b}.softmax", "softmax", (f"{b}.qk",)),
+            GNode(f"{b}.pv", "attn_pv", (f"{b}.softmax", f"{b}.v_cat")),
+        ]
+        for s in ("img",) if pre_only else ("img", "txt"):
+            p = f"{b}.{s}"
+            nodes += [
+                GNode(f"{p}.split", "split", (f"{b}.pv",)),
+                GNode(f"{p}.wo", "linear", (f"{p}.split",)),
+                GNode(f"{p}.gate1", "mul_act", (f"{p}.wo", f"{p}.mod")),
+                GNode(f"{p}.res1", "add", (prev[s], f"{p}.gate1")),
+                GNode(f"{p}.ln2", "norm", (f"{p}.res1",)),
+                GNode(f"{p}.modulate2", "modulate", (f"{p}.ln2", f"{p}.mod")),
+                GNode(f"{p}.wi", "linear", (f"{p}.modulate2",)),
+                GNode(f"{p}.gelu", "gelu", (f"{p}.wi",)),
+                GNode(f"{p}.wd", "linear", (f"{p}.gelu",)),
+                GNode(f"{p}.gate2", "mul_act", (f"{p}.wd", f"{p}.mod")),
+                GNode(f"{p}.res2", "add", (f"{p}.res1", f"{p}.gate2")),
+            ]
+            prev[s] = f"{p}.res2"
+    nodes += [
+        GNode("final.ln", "norm", (prev["img"],)),
+        GNode("final.out", "linear", ("final.ln",)),
+    ]
+    return nodes
+
+
 def ddpm_tiny_graph(n_blocks: int) -> list[GNode]:
     """Conv ResNet denoiser: skip connections / residual adds are
     diff-transparent, so some convs get boundary_in/out = False — the conv

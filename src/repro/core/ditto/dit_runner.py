@@ -1,11 +1,16 @@
-"""DiT denoiser executed through the DittoEngine (quantized serving path).
+"""DiT and MMDiT denoisers executed through the DittoEngine (quantized
+serving path).
 
-Mirrors repro.nn.dit.apply with every linear op routed through the engine.
-``_dit_forward`` is the single source of truth for the block structure; it
-takes the two engine ops as callables, so the eager calibration pass
-(:class:`DittoDiT`) and the jit-compiled Pallas execution pass
-(:class:`CompiledDittoDiT`) share the exact same forward — a structural
-divergence between the two phases is impossible by construction.
+Mirrors repro.nn.dit.apply (``DiTCfg``) and repro.nn.mmdit.apply
+(``MMDiTCfg``) with every linear op routed through the engine.
+``_dit_forward`` and ``_mmdit_forward`` are the single sources of truth for
+their block structures; each takes the two engine ops as callables, so the
+eager calibration pass (:class:`DittoDiT`) and the jit-compiled Pallas
+execution pass (:class:`CompiledDittoDiT`) share the exact same forward — a
+structural divergence between the two phases is impossible by
+construction. The config's type picks the forward, the Defo graph and the
+weights the engine registers (:func:`_arch`); the step, the runner and the
+denoising loop are one code path for both.
 
 ``make_denoise_fn(..., plan)`` with ``plan.compiled=True`` runs eager
 steps until the engine is calibrated (>= 1 step; for Defo policies, until
@@ -30,6 +35,7 @@ from ...distributed.sharding import constrain_batch
 from ...kernels.common import DEFAULT_LOW_BITS
 from ...nn import core as nncore
 from ...nn import dit as dit_mod
+from ...nn import mmdit as mmdit_mod
 from ..spans import span
 from . import compiled as compiled_mod
 from . import defo
@@ -76,6 +82,20 @@ def _cond_dense(p, c):
     return jax.lax.optimization_barrier(nncore.dense(p, jnp.concatenate([c, c])))[:1]
 
 
+def _patchify(latents, cfg):
+    b, hh, ww, ch = latents.shape
+    pp = cfg.patch
+    x = latents.reshape(b, hh // pp, pp, ww // pp, pp, ch)
+    return x.transpose(0, 1, 3, 2, 4, 5).reshape(b, cfg.n_tokens, cfg.patch_dim)
+
+
+def _unpatchify(x, shape, cfg):
+    b, hh, ww, ch = shape
+    pp = cfg.patch
+    x = x.reshape(b, hh // pp, ww // pp, pp, pp, ch).transpose(0, 1, 3, 2, 4, 5)
+    return x.reshape(b, hh, ww, ch)
+
+
 def _dit_forward(params, cfg: dit_mod.DiTCfg, linear, attention, latents, t, labels):
     """One DiT forward with every quantized op injected.
 
@@ -85,11 +105,9 @@ def _dit_forward(params, cfg: dit_mod.DiTCfg, linear, attention, latents, t, lab
     Each block runs under ``jax.named_scope("blk<i>")`` with ``attn`` and
     ``mlp`` sub-scopes, so device ops carry their layer in their metadata.
     """
-    b, hh, ww, ch = latents.shape
-    pp = cfg.patch
-    x = latents.reshape(b, hh // pp, pp, ww // pp, pp, ch)
-    x = x.transpose(0, 1, 3, 2, 4, 5).reshape(b, cfg.n_tokens, cfg.patch_dim)
-    x = nncore.dense(params["patch_embed"], x) + nncore.val(params["pos_embed"])[None]
+    b = latents.shape[0]
+    x = nncore.dense(params["patch_embed"], _patchify(latents, cfg))
+    x = x + nncore.val(params["pos_embed"])[None]
     c = dit_mod.timestep_embedding(t, 256)
     c = _cond_dense(params["t_mlp2"], jax.nn.silu(_cond_dense(params["t_mlp1"], c)))
     if labels is not None and "label_embed" in params:
@@ -128,8 +146,138 @@ def _dit_forward(params, cfg: dit_mod.DiTCfg, linear, attention, latents, t, lab
     shift, scl = jnp.split(modf, 2, axis=-1)
     x = dit_mod._modulate(dit_mod._ln(x), shift, scl)
     x = linear("final.out", x)
-    x = x.reshape(b, hh // pp, ww // pp, pp, pp, ch).transpose(0, 1, 3, 2, 4, 5)
-    return x.reshape(b, hh, ww, ch)
+    return _unpatchify(x, latents.shape, cfg)
+
+
+def _mmdit_forward(params, cfg: mmdit_mod.MMDiTCfg, linear, attention, latents, t, labels):
+    """One MMDiT forward with every quantized op injected, the contract of
+    :func:`_dit_forward`.
+
+    Per block, each stream's linears are ``blk<i>.{img,txt}.{mod,wq,wk,wv,
+    wo,wi,wd}`` (the last block's text stream has ``mod``, 2d wide, and
+    q/k/v only) and the joint attention's two products over ``[image;
+    text]`` tokens are ``blk<i>.qk`` and ``blk<i>.pv``. Patch embedding,
+    the timestep and pooled-text MLPs, ``context_embed`` and the final
+    modulation stay fp32. Scopes: ``blk<i>/img``, ``blk<i>/txt`` and
+    ``blk<i>/joint_attn``. ``labels`` are prompt ids (required).
+    """
+    b = latents.shape[0]
+    x = nncore.dense(params["patch_embed"], _patchify(latents, cfg))
+    x = x + nncore.val(params["pos_embed"])[None]
+    c = dit_mod.timestep_embedding(t, 256)
+    c = _cond_dense(params["t_mlp2"], jax.nn.silu(_cond_dense(params["t_mlp1"], c)))
+    pooled = nncore.val(params["prompt_pooled"])[labels]
+    c = c + _cond_dense(params["pool_mlp2"],
+                        jax.nn.silu(_cond_dense(params["pool_mlp1"], pooled)))
+    c_act = jax.nn.silu(c)
+    txt = nncore.dense(params["context_embed"], nncore.val(params["prompt_ctx"])[labels])
+
+    nh, hd, ti = cfg.n_heads, cfg.head_dim, cfg.n_tokens
+    n = ti + cfg.ctx_tokens
+    scale = 1.0 / math.sqrt(hd)
+
+    def heads(parts):  # [(b, T_s, d) per stream] -> (b * nh, n, hd)
+        z = jnp.concatenate(parts, axis=1).reshape(b, n, nh, hd)
+        return z.transpose(0, 2, 1, 3).reshape(b * nh, n, hd)
+
+    def qkv(pre, h):
+        return [linear(f"{pre}.{nm}", h) for nm in ("wq", "wk", "wv")]
+
+    def out_and_mlp(pre, z, a, mods):
+        _, _, g_a, sh_m, sc_m, g_m = mods
+        z = z + g_a[:, None, :] * linear(f"{pre}.wo", a)
+        h = dit_mod._modulate(dit_mod._ln(z), sh_m, sc_m)
+        return z + g_m[:, None, :] * linear(f"{pre}.wd", jax.nn.gelu(linear(f"{pre}.wi", h)))
+
+    for i in range(cfg.n_layers):
+        bk = f"blk{i}"
+        pre_only = i == cfg.n_layers - 1
+        with jax.named_scope(bk):
+            with jax.named_scope("img"):
+                im = jnp.split(linear(f"{bk}.img.mod", c_act), 6, axis=-1)
+                qi = qkv(f"{bk}.img", dit_mod._modulate(dit_mod._ln(x), im[0], im[1]))
+            with jax.named_scope("txt"):
+                if pre_only:  # AdaLayerNormContinuous: scale, then shift
+                    t_sc, t_sh = jnp.split(linear(f"{bk}.txt.mod", c_act), 2, axis=-1)
+                else:
+                    tm = jnp.split(linear(f"{bk}.txt.mod", c_act), 6, axis=-1)
+                    t_sh, t_sc = tm[0], tm[1]
+                qt = qkv(f"{bk}.txt", dit_mod._modulate(dit_mod._ln(txt), t_sh, t_sc))
+            with jax.named_scope("joint_attn"):
+                qf, kf, vf = (heads([a, b_]) for a, b_ in zip(qi, qt))
+                scores = attention(f"{bk}.qk", qf, kf) * scale
+                probs = jax.nn.softmax(scores, axis=-1)
+                av = attention(f"{bk}.pv", probs, vf.swapaxes(-1, -2))
+                av = av.reshape(b, nh, n, hd).transpose(0, 2, 1, 3).reshape(b, n, nh * hd)
+            with jax.named_scope("img"):
+                x = out_and_mlp(f"{bk}.img", x, av[:, :ti], im)
+            if not pre_only:
+                with jax.named_scope("txt"):
+                    txt = out_and_mlp(f"{bk}.txt", txt, av[:, ti:], tm)
+
+    scl, shift = jnp.split(_cond_dense(params["final_mod"], c_act), 2, axis=-1)
+    x = linear("final.out", dit_mod._modulate(dit_mod._ln(x), shift, scl))
+    return _unpatchify(x, latents.shape, cfg)
+
+
+def _register_dit(engine: DittoEngine, params, cfg: dit_mod.DiTCfg) -> None:
+    metas = defo.analyze(defo.dit_graph(cfg.n_layers))
+    blocks = params["blocks"]
+
+    def blk(i, *path):
+        cur = blocks
+        for p in path:
+            cur = cur[p]
+        return np.asarray(nncore.val(cur))[i]
+
+    for i in range(cfg.n_layers):
+        b = f"blk{i}"
+        engine.register_linear(metas[f"{b}.mod"], blk(i, "mod", "w"), blk(i, "mod", "b"))
+        for nm, pth in (("wq", ("attn", "wq")), ("wk", ("attn", "wk")), ("wv", ("attn", "wv")),
+                        ("wo", ("attn", "wo"))):
+            w = blk(i, *pth, "w")
+            bias = blk(i, *pth, "b")
+            engine.register_linear(metas[f"{b}.{nm}"], w, bias)
+        engine.register_attention(metas[f"{b}.qk"])
+        engine.register_attention(metas[f"{b}.pv"])
+        engine.register_linear(metas[f"{b}.wi"], blk(i, "mlp", "wi", "w"), blk(i, "mlp", "wi", "b"))
+        engine.register_linear(metas[f"{b}.wd"], blk(i, "mlp", "wo", "w"), blk(i, "mlp", "wo", "b"))
+    engine.register_linear(metas["final.out"], _v(params, "final_out", "w"), _v(params, "final_out", "b"))
+
+
+def mmdit_linear_weights(params, cfg: mmdit_mod.MMDiTCfg):
+    """``(call-site name, w, b)`` of every engine linear of an MMDiT, as
+    numpy arrays, in call order."""
+    for i in range(cfg.n_layers):
+        last = i == cfg.n_layers - 1
+        for s in ("img", "txt"):
+            names = (mmdit_mod.PRE_ONLY_LINEARS if last and s == "txt"
+                     else mmdit_mod.STREAM_LINEARS)
+            for nm in names:
+                if last:
+                    p = params["last"][s][nm]
+                    w, bias = _v(p, "w"), _v(p, "b")
+                else:
+                    p = params["blocks"][s][nm]
+                    w, bias = _v(p, "w")[i], _v(p, "b")[i]
+                yield f"blk{i}.{s}.{nm}", w, bias
+    yield "final.out", _v(params, "final_out", "w"), _v(params, "final_out", "b")
+
+
+def _register_mmdit(engine: DittoEngine, params, cfg: mmdit_mod.MMDiTCfg) -> None:
+    metas = defo.analyze(defo.mmdit_graph(cfg.n_layers))
+    for name, w, bias in mmdit_linear_weights(params, cfg):
+        engine.register_linear(metas[name], w, bias)
+    for i in range(cfg.n_layers):
+        engine.register_attention(metas[f"blk{i}.qk"])
+        engine.register_attention(metas[f"blk{i}.pv"])
+
+
+def _arch(cfg):
+    """``(forward, register)`` of the denoiser the config describes."""
+    if isinstance(cfg, mmdit_mod.MMDiTCfg):
+        return _mmdit_forward, _register_mmdit
+    return _dit_forward, _register_dit
 
 
 class DittoDiT:
@@ -138,40 +286,20 @@ class DittoDiT:
     Weights are registered once from the same param tree used for
     training."""
 
-    def __init__(self, params, cfg: dit_mod.DiTCfg, engine: DittoEngine):
+    def __init__(self, params, cfg: dit_mod.DiTCfg | mmdit_mod.MMDiTCfg, engine: DittoEngine):
         self.cfg = cfg
         self.engine = engine
         self.params = params
-        metas = defo.analyze(defo.dit_graph(cfg.n_layers))
-        blocks = params["blocks"]
-
-        def blk(i, *path):
-            cur = blocks
-            for p in path:
-                cur = cur[p]
-            return np.asarray(nncore.val(cur))[i]
-
-        for i in range(cfg.n_layers):
-            b = f"blk{i}"
-            engine.register_linear(metas[f"{b}.mod"], blk(i, "mod", "w"), blk(i, "mod", "b"))
-            for nm, pth in (("wq", ("attn", "wq")), ("wk", ("attn", "wk")), ("wv", ("attn", "wv")),
-                            ("wo", ("attn", "wo"))):
-                w = blk(i, *pth, "w")
-                bias = blk(i, *pth, "b")
-                engine.register_linear(metas[f"{b}.{nm}"], w, bias)
-            engine.register_attention(metas[f"{b}.qk"])
-            engine.register_attention(metas[f"{b}.pv"])
-            engine.register_linear(metas[f"{b}.wi"], blk(i, "mlp", "wi", "w"), blk(i, "mlp", "wi", "b"))
-            engine.register_linear(metas[f"{b}.wd"], blk(i, "mlp", "wo", "w"), blk(i, "mlp", "wo", "b"))
-        engine.register_linear(metas["final.out"], _v(params, "final_out", "w"), _v(params, "final_out", "b"))
+        self._forward, register = _arch(cfg)
+        register(engine, params, cfg)
 
     def __call__(self, latents, t, labels=None):
         eng = self.engine
-        return _dit_forward(self.params, self.cfg, eng.linear, eng.attention_matmul,
-                            latents, t, labels)
+        return self._forward(self.params, self.cfg, eng.linear, eng.attention_matmul,
+                             latents, t, labels)
 
 
-def make_step_fn(cfg: dit_mod.DiTCfg, modes: dict[str, str], plan: DittoPlan | None = None,
+def make_step_fn(cfg: dit_mod.DiTCfg | mmdit_mod.MMDiTCfg, modes: dict[str, str], plan: DittoPlan | None = None,
                  *, block=UNSET, interpret=UNSET, collect_stats=UNSET,
                  low_bits=UNSET, fused=UNSET):
     """Build the pure per-step function of the compiled execution pass.
@@ -217,6 +345,7 @@ def make_step_fn(cfg: dit_mod.DiTCfg, modes: dict[str, str], plan: DittoPlan | N
     # MESH_SIG_FIELDS are cache_sig() fields. mesh_sig=None leaves the
     # jaxpr untouched (bit-for-bit the pre-mesh trace).
     msig = plan.mesh_sig()
+    forward, _ = _arch(cfg)
 
     def step(dparams, mparams, state, latents, t, labels):
         latents = constrain_batch(latents, msig)
@@ -235,7 +364,7 @@ def make_step_fn(cfg: dit_mod.DiTCfg, modes: dict[str, str], plan: DittoPlan | N
             new_state[name], aux[name] = st2, a
             return y
 
-        out = _dit_forward(mparams, cfg, lin, attn, latents, t, labels)
+        out = forward(mparams, cfg, lin, attn, latents, t, labels)
         totals = state[TILE_TOTALS]
         if any("tile_hist" in a for a in aux.values()):
             zero = jnp.zeros((3,), jnp.int32)
@@ -260,7 +389,7 @@ class CompiledDittoDiT:
     per instance, so later batches with the same (cfg, modes,
     ``plan.cache_sig()``, ``bucket``, shapes) reuse the existing trace."""
 
-    def __init__(self, params, cfg: dit_mod.DiTCfg, engine: DittoEngine,
+    def __init__(self, params, cfg: dit_mod.DiTCfg | mmdit_mod.MMDiTCfg, engine: DittoEngine,
                  plan: DittoPlan | None = None, *, cache=None, bucket: int | None = None,
                  interpret=UNSET, collect_stats=UNSET, block=UNSET, low_bits=UNSET,
                  fused=UNSET, cache_extra=UNSET):
@@ -289,7 +418,7 @@ class CompiledDittoDiT:
         return out
 
 
-def make_denoise_fn(params, cfg: dit_mod.DiTCfg, engine: DittoEngine,
+def make_denoise_fn(params, cfg: dit_mod.DiTCfg | mmdit_mod.MMDiTCfg, engine: DittoEngine,
                     plan: DittoPlan | None = None, *, runner_cache=None,
                     bucket: int | None = None, compiled=UNSET, interpret=UNSET,
                     collect_stats=UNSET, block=UNSET, low_bits=UNSET, fused=UNSET,

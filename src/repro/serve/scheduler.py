@@ -1064,6 +1064,9 @@ class ServeScheduler:
                             out[k] = out.get(k, 0) + v
                         out["tiles"] = [a + b for a, b in
                                         zip(out.get("tiles", (0, 0, 0)), s.tiles)]
+                        out["tiles_txt"] = [a + b for a, b in
+                                            zip(out.get("tiles_txt", (0, 0, 0)),
+                                                s.tiles_txt)]
                 cache = getattr(self.session, "cache", None)
                 if cache is not None:
                     out.update(cache.stats())

@@ -46,6 +46,8 @@ from .cache import CompiledRunnerCache
 
 #: scalar counters a served chunk reports and ``ServeSession.stats()`` sums
 STEP_COUNTERS = ("host_reads", "eager_steps", "compiled_steps")
+#: a layer whose call-site name holds this runs on an MMDiT text stream
+TEXT_STREAM = ".txt."
 
 
 @dataclasses.dataclass
@@ -152,6 +154,7 @@ class ServeSession:
         self.watchdog_events = 0  # re-anchor steps across all served chunks
         self.counters = dict.fromkeys(STEP_COUNTERS, 0)
         self.tiles = [0, 0, 0]  # (zero, low, full) diff tiles, all layers
+        self.tiles_txt = [0, 0, 0]  # the same, layers of a text stream (".txt.")
         self._dispatch_ids = itertools.count()  # serve.dispatch span index
         # sessions are documented as shareable across request threads (one
         # shared cache); bare += on the counters would drop increments
@@ -193,8 +196,10 @@ class ServeSession:
             self.watchdog_events += events
             for k in STEP_COUNTERS:
                 self.counters[k] += counters[k]
-            for h in counters["tile_hist"].values():
+            for name, h in counters["tile_hist"].items():
                 self.tiles = [a + b for a, b in zip(self.tiles, h)]
+                if TEXT_STREAM in name:
+                    self.tiles_txt = [a + b for a, b in zip(self.tiles_txt, h)]
         return result
 
     def _serve_chunk(self, x, labels, plan: DittoPlan | PlanSchedule) -> ChunkResult:
@@ -227,4 +232,5 @@ class ServeSession:
         with self._stats_lock:
             return {"batches": self.batches_served, "requests": self.requests_served,
                     "watchdog_events": self.watchdog_events, **self.counters,
-                    "tiles": list(self.tiles), **self.cache.stats()}
+                    "tiles": list(self.tiles), "tiles_txt": list(self.tiles_txt),
+                    **self.cache.stats()}

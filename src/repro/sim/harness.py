@@ -14,6 +14,7 @@ from ..core.spans import span
 from ..core.ditto import CAMBRICON_D, DIFFY, DITTO_HW, ITC, DittoEngine, make_denoise_fn
 from ..core.ditto.plan import UNSET, DittoPlan, PlanSchedule, plan_from_kwargs
 from ..nn import dit as dit_mod
+from ..nn import mmdit as mmdit_mod
 from . import cycles
 
 DESIGN_HW = {
@@ -31,7 +32,7 @@ GPU_TOPS = 624e12 * 0.03
 GPU_BW = 1.555e12
 
 
-def collect_records(params, cfg: dit_mod.DiTCfg, sched, x_T, labels, *, steps: int,
+def collect_records(params, cfg: dit_mod.DiTCfg | mmdit_mod.MMDiTCfg, sched, x_T, labels, *, steps: int,
                     sampler: str = "ddim"):
     """One exact engine pass collecting act/diff/spatial stats per record."""
     eng = DittoEngine(policy="diff", collect_oracle=True)
@@ -41,7 +42,7 @@ def collect_records(params, cfg: dit_mod.DiTCfg, sched, x_T, labels, *, steps: i
     return eng.records, sample, eng
 
 
-def serve_records(params, cfg: dit_mod.DiTCfg, sched, x_T, labels=None,
+def serve_records(params, cfg: dit_mod.DiTCfg | mmdit_mod.MMDiTCfg, sched, x_T, labels=None,
                   plan: DittoPlan | PlanSchedule | None = None, *, runner_cache=None,
                   bucket: int | None = None, mesh=None, steps=UNSET, sampler=UNSET,
                   policy=UNSET, compiled=UNSET, interpret=UNSET, collect_stats=UNSET,
@@ -127,7 +128,7 @@ def gpu_baseline(records) -> dict:
     return {"hw": "gpu-a100", "time_s": t, "energy_j": t * 300.0, "cycles": t * 1.41e9}
 
 
-def run_all(params, cfg: dit_mod.DiTCfg, sched, x_T, labels, *, steps: int,
+def run_all(params, cfg: dit_mod.DiTCfg | mmdit_mod.MMDiTCfg, sched, x_T, labels, *, steps: int,
             sampler: str = "ddim", t_mult: float = 1.0, d_mult: float = 1.0,
             seq_mult: float | None = None):
     records, sample, eng = collect_records(params, cfg, sched, x_T, labels,

@@ -1,8 +1,9 @@
 """Compile rehearsal: the main-path Pallas kernels for a described TPU v5e.
 
 Each test lowers and compiles with ``interpret=False`` at DiT-XL/2 widths
-(d 1152, d_ff 4608, 256 tokens per sample, head_dim 72) for one chip of a
-described ``v5e:2x2`` topology, and asserts the executable holds a Mosaic
+(d 1152, d_ff 4608, 256 tokens per sample, head_dim 72), or at SD3-medium's
+MMDiT widths (d 1536, 24 heads of 64, 1,024 image and 154 text tokens), for
+one chip of a described ``v5e:2x2`` topology, and asserts the executable holds a Mosaic
 kernel (``tpu_custom_call``). Nothing runs: this catches what the chip's
 compiler refuses (block layouts, operand dtypes, VMEM) at no chip time.
 The topology is described inside a fixture, never at import.
@@ -19,6 +20,7 @@ from repro.core.ditto import dit_runner
 from repro.core.ditto.plan import DittoPlan
 from repro.kernels import ops
 from repro.nn import dit as dit_mod
+from repro.nn import mmdit
 
 D, FF, TOKENS, HEAD_DIM = 1152, 4608, 256, 72
 ROWS = 4 * TOKENS  # a bucket-4 dispatch
@@ -88,10 +90,14 @@ def test_attention_delta_compiles(one_chip):
     assert "tpu_custom_call" in text
 
 
-def _serving_step_text(one_chip, mode: str, bucket: int) -> str:
-    """Compiled HLO of the whole jitted serving step (one DiT-XL/2 block at
-    full width) at ``bucket`` rows — the program the runner cache compiles."""
-    cfg = dit_mod.DiTCfg(d_model=D, n_layers=1, n_heads=16, n_classes=1000)
+DIT_XL2_BLOCK = dit_mod.DiTCfg(d_model=D, n_layers=1, n_heads=16, n_classes=1000)
+# one full block and the context_pre_only block of SD3-medium at 512x512
+SD3_TWO_BLOCKS = mmdit.MMDiTCfg(d_model=1536, n_layers=2, n_heads=24, n_prompts=2)
+
+
+def _serving_step_text(one_chip, mode: str, bucket: int, cfg=DIT_XL2_BLOCK) -> str:
+    """Compiled HLO of the whole jitted serving step at full width at
+    ``bucket`` rows — the program the runner cache compiles."""
     plan = DittoPlan(interpret=False, collect_stats=False)
     step = dit_runner.make_step_fn(cfg, ta.uniform_modes(cfg, mode), plan)
     dparams, mparams, lat, t, labels = ta.abstract_inputs(cfg, bucket)
@@ -104,6 +110,13 @@ def _serving_step_text(one_chip, mode: str, bucket: int) -> str:
 @pytest.mark.parametrize("mode", ["act", "diff"])
 def test_one_layer_serving_step_compiles(one_chip, mode):
     assert "tpu_custom_call" in _serving_step_text(one_chip, mode, 4)
+
+
+@pytest.mark.parametrize("mode", ["act", "diff"])
+def test_mmdit_serving_step_compiles(one_chip, mode):
+    """The joint attention's 1,178 rows and the text stream's 154 are off
+    the 128-row tile grid: the kernels' edge tiles at their real sizes."""
+    assert "tpu_custom_call" in _serving_step_text(one_chip, mode, 1, SD3_TWO_BLOCKS)
 
 
 def test_one_row_serving_step_keeps_float_matmuls_on_the_mxu(one_chip):

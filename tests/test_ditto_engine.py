@@ -115,6 +115,45 @@ def test_defo_static_analysis_conv():
     assert metas["conv_out"].boundary_in
 
 
+def _linear_sources(graph, node, seen=None):
+    """The linear ops that feed ``node`` through diff-transparent ops."""
+    seen = set() if seen is None else seen
+    out = set()
+    for p in defo._producers(graph, node):
+        if p.name in seen:
+            continue
+        seen.add(p.name)
+        if p.op in defo.LINEAR_OPS:
+            out.add(p.name)
+        elif p.op in defo.TRANSPARENT:
+            out |= _linear_sources(graph, p, seen)
+    return out
+
+
+def test_defo_static_analysis_mmdit():
+    """Both streams carry the DiT block's fences, leaf for leaf, and the
+    joint attention is fed by both streams' projections."""
+    n = 3
+    nodes = defo.mmdit_graph(n)
+    metas = defo.analyze(nodes)
+    dit = defo.analyze(defo.dit_graph(n))
+    linears = {k for k, m in metas.items() if m.kind == "dense"}
+    assert len(linears) == (n - 1) * 2 * 7 + 7 + 4 + 1
+    for name in linears:
+        leaf = "final.out" if name == "final.out" else f"blk0.{name.split('.')[-1]}"
+        flags = (metas[name].boundary_in, metas[name].boundary_out)
+        assert flags == (dit[leaf].boundary_in, dit[leaf].boundary_out), name
+        assert any(flags), name  # a non-linear op on at least one side
+    graph = {g.name: g for g in nodes}
+    for i in range(n):
+        assert _linear_sources(graph, graph[f"blk{i}.qk"]) == {
+            f"blk{i}.{s}.w{x}" for s in ("img", "txt") for x in ("q", "k")}
+        # the probabilities come through the softmax (a fence), v from both streams
+        assert _linear_sources(graph, graph[f"blk{i}.pv"]) == {
+            f"blk{i}.img.wv", f"blk{i}.txt.wv"}
+    assert "blk2.txt.wo" not in metas and "blk1.txt.wo" in metas
+
+
 def test_full_sampler_loop_with_engine(setup):
     params, lat, labels = setup
     sched = diffusion.cosine_schedule(100)

@@ -153,6 +153,7 @@ class _ShardSession:
         self.watchdog_events = 0
         self.counters = {"host_reads": 0, "eager_steps": 0, "compiled_steps": 0}
         self.tiles = [0, 0, 0]
+        self.tiles_txt = [0, 0, 0]
         self._stats_lock = threading.Lock()
 
     def serve(self, x, labels, plan=None):
@@ -250,6 +251,7 @@ def test_mesh_stats_shape():
     assert st["mesh"]["shard_dispatches"] == [1, 0]
     assert st["mesh"]["shard_rows"] == [4, 0]
     assert st["batches"] == 1  # summed across per-shard sessions
+    assert st["tiles"] == st["tiles_txt"] == [0, 0, 0]
     s.close(drain=False)
 
 

@@ -119,6 +119,7 @@ def test_counters_reach_session_and_scheduler_stats(served, stats):
         assert got[k] == result.counters[k]
     assert got["tiles"] == [sum(h[i] for h in result.counters["tile_hist"].values())
                             for i in range(3)]
+    assert got["tiles_txt"] == [0, 0, 0]  # a DiT has no text stream
     assert s.session.stats()["compiled_steps"] == result.counters["compiled_steps"]
 
 

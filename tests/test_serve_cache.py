@@ -188,6 +188,21 @@ def test_cache_key_misses_when_only_low_bits_differs():
     assert cache.hits == 1
 
 
+def test_dit_and_mmdit_configs_of_equal_sizes_never_share_a_key():
+    """The runner key's config signature names the config class: a DiT and
+    an MMDiT with the same sizes trace different forwards."""
+    from repro.nn import mmdit
+
+    shared = dict(d_model=64, n_layers=2, n_heads=2, patch=2, in_channels=4, input_size=8)
+    dit, mm = dit_mod.DiTCfg(**shared), mmdit.MMDiTCfg(**shared)
+    cache = CompiledRunnerCache()
+    modes = {"final.out": "act"}
+    plan = DittoPlan(steps=4)
+    assert cache.key_for(dit, modes, plan, bucket=1) != cache.key_for(mm, modes, plan, bucket=1)
+    assert cache.step_for(dit, modes, plan, bucket=1) is not cache.step_for(mm, modes, plan,
+                                                                            bucket=1)
+
+
 def test_plan_only_loop_fields_share_a_key():
     """sampler/policy/compiled/max_batch shape the loop AROUND the step,
     not the step itself — plans differing only there must share a trace."""
