@@ -4,6 +4,14 @@ The samplers drive a generic ``denoise_fn(x_t, t, labels) -> eps_hat``;
 Ditto wraps that callable with temporal-difference processing (the
 iterative sampler loop is exactly the temporal axis the paper exploits).
 Each sampler step is a ``diffusion.step`` host span (:mod:`repro.core.spans`).
+
+A ``denoise_fn`` may also offer ``denoise_fn.segment_loop``: a callable
+with a ``ready()`` method that runs the remaining steps of a DDIM sample in
+one call, on the device (``dit_runner.make_denoise_fn``). ``ddim_sample``
+hands it the rest of the trajectory once it is ready, with the sampler's
+pure update (:func:`loop_update`), unless the caller passed a ``callback``,
+which needs each step's ``x`` on the host. ``plms_sample`` keeps its host
+loop: its multistep history would have to ride in the loop's carry.
 """
 from __future__ import annotations
 
@@ -11,21 +19,27 @@ import dataclasses
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from .spans import span
 
 
+@jax.tree_util.register_dataclass
 @dataclasses.dataclass(frozen=True)
 class NoiseSchedule:
+    """A pytree of two arrays, so a jitted sampler loop takes the schedule
+    as an argument. ``alpha_bars`` (the cumulative product of the alphas)
+    is computed once, when the schedule is built from its ``betas``."""
     betas: jnp.ndarray  # (T,)
+    alpha_bars: jnp.ndarray | None = None  # (T,)
+
+    def __post_init__(self):
+        if self.alpha_bars is None:
+            object.__setattr__(self, "alpha_bars", jnp.cumprod(self.alphas))
 
     @property
     def alphas(self):
         return 1.0 - self.betas
-
-    @property
-    def alpha_bars(self):
-        return jnp.cumprod(self.alphas)
 
     @property
     def T(self) -> int:
@@ -67,18 +81,35 @@ def ddim_step(sched: NoiseSchedule, x_t, eps_hat, t, t_prev, *, eta: float = 0.0
     return jnp.sqrt(abar_p) * x0_pred + dir_xt
 
 
+def loop_update(sampler: str, sched: NoiseSchedule):
+    """``update(x_t, eps_hat, t, t_prev) -> x_{t_prev}``, the sampler's step
+    as a pytree callable (``jax.tree_util.Partial``): pure in ``t`` and
+    ``t_prev`` (traced ints are fine), with the schedule's arrays as its
+    leaves, so a jitted loop takes it as an argument and one trace serves
+    any schedule of the same length. ``None`` for a sampler that keeps its
+    host loop (PLMS)."""
+    if sampler != "ddim":
+        return None
+    return jax.tree_util.Partial(ddim_step, sched)
+
+
 def ddim_sample(sched: NoiseSchedule, denoise_fn, x_T, *, steps: int, labels=None, callback=None):
     """Full DDIM sampling loop (python loop: each step may change execution
-    mode under Ditto/Defo, which is the point of the paper)."""
-    ts = ddim_timesteps(sched.T, steps)
+    mode under Ditto/Defo, which is the point of the paper). Once the
+    denoiser's ``segment_loop`` is ready, the remaining steps run there."""
+    ts = np.asarray(ddim_timesteps(sched.T, steps))
+    update = loop_update("ddim", sched)
+    loop = getattr(denoise_fn, "segment_loop", None) if callback is None else None
     x = x_T
     for i in range(len(ts)):
+        if loop is not None and loop.ready():
+            return loop(x, ts[i:], labels, update)
         with span("diffusion.step", step=i):
             t = int(ts[i])
             t_prev = int(ts[i + 1]) if i + 1 < len(ts) else -1
             t_vec = jnp.full((x.shape[0],), t, jnp.int32)
             eps_hat = denoise_fn(x, t_vec, labels)
-            x = ddim_step(sched, x, eps_hat, t, t_prev)
+            x = update(x, eps_hat, t, t_prev)
             if callback is not None:
                 callback(step_index=i, t=t, x=x)
     return x
@@ -86,7 +117,7 @@ def ddim_sample(sched: NoiseSchedule, denoise_fn, x_T, *, steps: int, labels=Non
 
 def plms_sample(sched: NoiseSchedule, denoise_fn, x_T, *, steps: int, labels=None, callback=None):
     """Pseudo linear multistep (PLMS, arXiv:2202.09778) — SDM's sampler."""
-    ts = ddim_timesteps(sched.T, steps)
+    ts = np.asarray(ddim_timesteps(sched.T, steps))
     x = x_T
     eps_hist: list = []
     for i in range(len(ts)):

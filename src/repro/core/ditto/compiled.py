@@ -104,6 +104,11 @@ class PackedStats:
             offsets[is_int] = off + size
         return jax.tree_util.tree_unflatten(treedef, leaves)
 
+    def unstack(self) -> list:
+        """One :class:`PackedStats` per step of a stack that a segment loop
+        returns (``fracs``/``tiles`` with a leading step axis)."""
+        return [PackedStats(f, t, self.layout) for f, t in zip(self.fracs, self.tiles)]
+
 
 def pack_stats(aux: dict) -> PackedStats:
     """Pack a step's aux pytree into a :class:`PackedStats` (inside the

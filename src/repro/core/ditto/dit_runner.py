@@ -18,8 +18,12 @@ the step-2 decision), then hands the remaining denoising steps to the
 compiled per-step function in which each layer's mode is a static
 bake-in: act-mode layers hit the ``int8_matmul`` Pallas kernel, diff-mode
 layers ``diff_encode`` -> ``ditto_diff_matmul`` (zero tiles skipped
-on-device). The plan (one ``repro.core.ditto.DittoPlan``) carries every
-knob; its ``cache_sig()`` is the runner-cache trace identity. fp32-mode
+on-device). Under DDIM with the watchdog off, the sampler hands those
+steps over at once (``denoise_fn.segment_loop``): each plan segment runs
+as one jitted ``lax.scan`` of that same step and the sampler's update
+(:func:`make_loop_fn`). The plan (one ``repro.core.ditto.DittoPlan``)
+carries every knob; its ``cache_sig()`` is the runner-cache trace
+identity. fp32-mode
 equivalence against nn.dit.apply is tested in tests/test_ditto_engine.py;
 eager/compiled bit-identity in tests/test_compiled_engine.py.
 """
@@ -376,6 +380,37 @@ def make_step_fn(cfg: dit_mod.DiTCfg | mmdit_mod.MMDiTCfg, modes: dict[str, str]
     return step
 
 
+def make_loop_fn(cfg: dit_mod.DiTCfg | mmdit_mod.MMDiTCfg, modes: dict[str, str],
+                 plan: DittoPlan | None = None):
+    """Build the pure segment loop of the compiled execution pass.
+
+    Returns ``denoise_loop(ditto_params, model_params, state, latents, ts,
+    labels, update) -> (latents, new_state, stats)``: ``len(ts) - 1``
+    steps of :func:`make_step_fn`'s ``step`` as one ``lax.scan``, each
+    followed by the sampler's ``update(x, eps_hat, t, t_prev)``
+    (``diffusion.loop_update``). ``ts`` holds the segment's timesteps and
+    then the one after it (-1 after the sample's last step). The carry is
+    ``(latents, state)``; ``stats`` is each step's
+    :class:`compiled.PackedStats`, stacked on a leading step axis. The
+    timesteps and the update (with its schedule) are arguments, so one
+    trace serves any schedule of the same length; ``cfg``, ``modes`` and
+    the segment-resolved ``plan`` are trace-static, as for the step."""
+    step = make_step_fn(cfg, modes, plan)
+
+    def denoise_loop(dparams, mparams, state, latents, ts, labels, update):
+        def body(carry, tt):
+            x, st = carry
+            t, t_prev = tt
+            eps, st, stats = step(dparams, mparams, st, x,
+                                  jnp.full((x.shape[0],), t, jnp.int32), labels)
+            return (update(x, eps, t, t_prev), st), stats
+
+        (latents, state), stats = jax.lax.scan(body, (latents, state), (ts[:-1], ts[1:]))
+        return latents, state, stats
+
+    return denoise_loop
+
+
 class CompiledDittoDiT:
     """Compiled execution pass: ONE jitted per-step function over the whole
     denoiser, built from a calibrated engine. Per-layer temporal state
@@ -383,11 +418,13 @@ class CompiledDittoDiT:
     frozen at trace time. With collect_stats, on-device class fractions
     come back packed with the tile histograms, are read in one transfer,
     and the engine synthesizes cost-model records for the step.
+    :meth:`loop` runs several steps of one segment in one call instead.
 
     With ``cache`` (a :class:`repro.serve.CompiledRunnerCache`) the jitted
-    step is fetched from / registered in the cache instead of being jitted
-    per instance, so later batches with the same (cfg, modes,
-    ``plan.cache_sig()``, ``bucket``, shapes) reuse the existing trace."""
+    step or loop is fetched from / registered in the cache when first
+    called instead of being jitted per instance, so later batches with the
+    same (cfg, modes, ``plan.cache_sig()``, ``bucket``, shapes) reuse the
+    existing trace."""
 
     def __init__(self, params, cfg: dit_mod.DiTCfg | mmdit_mod.MMDiTCfg, engine: DittoEngine,
                  plan: DittoPlan | None = None, *, cache=None, bucket: int | None = None,
@@ -404,17 +441,43 @@ class CompiledDittoDiT:
         self.plan = plan
         self.ceng = CompiledDittoEngine(engine, plan=plan)
         self.state = self.ceng.init_state()
-        if cache is not None:
-            self._step = cache.step_for(cfg, self.ceng.modes, plan, bucket=bucket)
-        else:
-            self._step = jax.jit(make_step_fn(cfg, self.ceng.modes, plan))
+        self._cache, self._bucket = cache, bucket
+        self._step = self._loop = None
 
     def __call__(self, latents, t, labels=None):
+        if self._step is None:
+            self._step = (self._cache.step_for(self.cfg, self.ceng.modes, self.plan,
+                                               bucket=self._bucket)
+                          if self._cache is not None else
+                          jax.jit(make_step_fn(self.cfg, self.ceng.modes, self.plan)))
         out, self.state, stats = self._step(self.ceng.params, self.params, self.state,
                                             latents, t, labels)
         if self.ceng.collect_stats:
             with span("ditto.record_step", step=self.engine.step_idx):
                 self.engine.record_compiled_step(stats)
+        return out
+
+    def loop(self, latents, ts, labels, update):
+        """Run the ``len(ts) - 1`` steps of one segment from
+        ``engine.step_idx`` on (:func:`make_loop_fn`) in one call, then
+        read their stacked statistics in one transfer and record every
+        step. The input state is donated: the loop carries the only live
+        copy of it."""
+        n = len(ts) - 1
+        if self._cache is not None:
+            fn = self._cache.loop_for(self.cfg, self.ceng.modes, self.plan,
+                                      bucket=self._bucket, update=update, length=n)
+        else:
+            if self._loop is None:
+                self._loop = jax.jit(make_loop_fn(self.cfg, self.ceng.modes, self.plan),
+                                     donate_argnums=2)
+            fn = self._loop
+        with span("ditto.loop", step=self.engine.step_idx, steps=n):
+            out, self.state, stats = fn(self.ceng.params, self.params, self.state,
+                                        latents, ts, labels, update)
+        if self.ceng.collect_stats:
+            with span("ditto.record_step", step=self.engine.step_idx):
+                self.engine.record_compiled_steps(stats)
         return out
 
 
@@ -447,6 +510,16 @@ def make_denoise_fn(params, cfg: dit_mod.DiTCfg | mmdit_mod.MMDiTCfg, engine: Di
     calibration steps predate the compiled path and ignore segment kernel
     knobs (the eager engine has none).
 
+    With ``plan.compiled``, the engine calibrated and the watchdog off,
+    ``fn.segment_loop`` is ready: ``ddim_sample`` then hands it the
+    remaining timesteps and its update, and it runs them as one
+    :meth:`CompiledDittoDiT.loop` call per plan segment, advancing
+    ``engine.step_idx`` by each segment's length and counting its steps in
+    ``compiled_steps`` and ``looped_steps``. Records, tile totals and the
+    state carried across segment boundaries are those of the per-step
+    path, which still runs under the watchdog, PLMS and a sampler
+    ``callback``.
+
     ``plan.watchdog=True`` arms the numerical health watchdog on the
     compiled path: every step's output is finite-guarded, and (with
     ``plan.reanchor_full_frac``) the measured tile-class histograms are
@@ -478,6 +551,9 @@ def make_denoise_fn(params, cfg: dit_mod.DiTCfg | mmdit_mod.MMDiTCfg, engine: Di
         from ...serve import faults as faults_mod
     runner = DittoDiT(params, cfg, engine)
     box: dict = {}
+    # the step after each segment's last; a bare plan is one open segment
+    seg_stops = ([stop for _, stop, _ in schedule.segment_plans()]
+                 if schedule is not None else [])
 
     def reanchor_step(x, t, labels, trigger: str, extra: dict):
         """Run THIS step full-bit-width (all layers act mode) under the
@@ -540,11 +616,9 @@ def make_denoise_fn(params, cfg: dit_mod.DiTCfg | mmdit_mod.MMDiTCfg, engine: Di
                 box["reanchor_due"] = full / total
         return out
 
-    def compiled_step(x, t, labels):
-        # engine.step_idx is the current sampler step (end_step() advances
-        # it; both samplers call fn once per step)
-        seg_plan = (schedule.plan_for(engine.step_idx) if schedule is not None
-                    else plan)
+    def runner_for(step: int) -> CompiledDittoDiT:
+        """The compiled runner of the segment holding sampler step ``step``."""
+        seg_plan = schedule.plan_for(step) if schedule is not None else plan
         sig = seg_plan.cache_sig()
         if box.get("built_for") is not engine.records:  # rebuilt per begin_sample
             with span("ditto.runner_build"):
@@ -560,6 +634,12 @@ def make_denoise_fn(params, cfg: dit_mod.DiTCfg | mmdit_mod.MMDiTCfg, engine: Di
                                                  cache=runner_cache, bucket=bucket)
             box["runner"].state = prev.state
             box["sig"] = sig
+        return box["runner"]
+
+    def compiled_step(x, t, labels):
+        # engine.step_idx is the current sampler step (end_step() advances
+        # it; both samplers call fn once per step)
+        runner_for(engine.step_idx)
         if watchdog:
             out = guarded_step(x, t, labels)
             if not engine.host_read(jnp.isfinite(out).all(), bool):
@@ -582,4 +662,25 @@ def make_denoise_fn(params, cfg: dit_mod.DiTCfg | mmdit_mod.MMDiTCfg, engine: Di
             engine.end_step()
         return out
 
+    def segment_loop(x, ts, labels, update):
+        """Run the sampler's remaining timesteps ``ts`` from
+        ``engine.step_idx`` on, one device loop per plan segment."""
+        ts = np.append(ts, -1).astype(np.int32)  # -1: the step after the last
+        done = 0
+        while done < len(ts) - 1:
+            k = engine.step_idx
+            left = len(ts) - 1 - done
+            n = min(next((s - k for s in seg_stops if s > k), left), left)
+            cur = runner_for(k)
+            x = cur.loop(x, ts[done:done + n + 1], labels, update)
+            engine.tile_totals = cur.state[TILE_TOTALS]
+            engine.step_idx += n
+            engine.compiled_steps += n
+            engine.looped_steps += n
+            done += n
+        return x
+
+    segment_loop.ready = lambda: (plan.compiled and not watchdog
+                                  and engine.ready_for_compiled())
+    fn.segment_loop = segment_loop
     return fn

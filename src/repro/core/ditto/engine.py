@@ -103,6 +103,7 @@ class DittoEngine:
         self.host_reads = 0  # blocking device->host reads of this sample
         self.eager_steps = 0
         self.compiled_steps = 0
+        self.looped_steps = 0  # compiled steps run inside a segment loop
         # device (layers, 3) int32 (zero, low, full) tile totals of the
         # compiled steps, rows in sorted layer order (make_step_fn)
         self.tile_totals = None
@@ -127,7 +128,8 @@ class DittoEngine:
                      for name, row in zip(sorted(self.layers), rows)
                      if modes[name] == "diff"}
         return {"host_reads": self.host_reads, "eager_steps": self.eager_steps,
-                "compiled_steps": self.compiled_steps, "tile_hist": tiles}
+                "compiled_steps": self.compiled_steps, "looped_steps": self.looped_steps,
+                "tile_hist": tiles}
 
     def end_step(self):
         self.step_idx += 1
@@ -397,6 +399,22 @@ class DittoEngine:
         latents/batch), which is exactly what lets the step be jitted in
         the first place.
         """
+        aux = self.host_read(stats, jax.device_get).unpack()
+        self._record_aux(aux, self.step_idx, modes=modes, reanchor=reanchor)
+
+    def record_compiled_steps(self, stats) -> None:
+        """Append the records of every step of one segment loop, the
+        steps from ``step_idx`` on: ``stats`` is the loop's
+        ``compiled.PackedStats`` stacked on a leading step axis, fetched
+        with one ``jax.device_get`` (one counted host read). Each step's
+        records are those :meth:`record_compiled_step` gives for it."""
+        host = self.host_read(stats, jax.device_get)
+        for i, one in enumerate(host.unstack()):
+            self._record_aux(one.unpack(), self.step_idx + i)
+
+    def _record_aux(self, aux: dict, step: int, *, modes: dict[str, str] | None = None,
+                    reanchor: bool = False) -> None:
+        """The records of one compiled step from its unpacked aux pytree."""
         if self._compiled_base is None:
             base_by_layer: dict[str, dict] = {}
             for r in self.records:
@@ -405,11 +423,10 @@ class DittoEngine:
         base_modes, base_by_layer = self._compiled_base
         if modes is None:
             modes = base_modes
-        aux = self.host_read(stats, jax.device_get).unpack()
         for name, a in aux.items():
             base = base_by_layer[name]
             meta = self.meta[name]
-            rec: dict[str, Any] = {"layer": name, "step": self.step_idx, "mode": modes[name],
+            rec: dict[str, Any] = {"layer": name, "step": step, "mode": modes[name],
                                    "kind": meta.kind, "macs": base["macs"], "compiled": True}
             if reanchor:
                 rec["reanchor"] = True

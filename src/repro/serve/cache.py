@@ -25,6 +25,11 @@ shards of one :class:`~repro.serve.mesh.ServeMesh` DO share every trace,
 because a shard's identity is its width and axis name, never its
 concrete devices.
 
+The same store holds each segment loop (``dit_runner.make_loop_fn``: a
+segment's compiled steps and the sampler's update as one ``lax.scan``),
+under the step's key plus the sampler's update and the loop's length
+(``RunnerKey.loop``), with the same trace and AOT counters.
+
 The key is shared by every subsequent batch that maps to it (and shapes —
 which the batch bucket pins). The cache counts actual Python traces via a
 trace-time side effect, so tests can assert "N same-bucket batches
@@ -142,6 +147,8 @@ class RunnerKey:
     mode_sig: tuple
     plan_sig: tuple  # DittoPlan.cache_sig(), ordered — see accessors below
     bucket: int | None = None
+    # (sampler update, steps) of a segment loop; None for the per-step runner
+    loop: tuple | None = None
 
     # ------------------------------------------------- plan_sig accessors
     # plan_sig's field order is DittoPlan.cache_sig()'s stable contract
@@ -282,7 +289,6 @@ class CompiledRunnerCache:
             if key in self._steps:
                 self.hits += 1
                 return self._steps[key]
-            self.misses += 1
             raw = dit_runner.make_step_fn(cfg, modes, plan)
 
             def counting_step(*args):
@@ -293,17 +299,52 @@ class CompiledRunnerCache:
                 self._count_trace(key)
                 return raw(*args)
 
-            runner = _Runner(jax.jit(counting_step), self)
-            self._steps[key] = runner
-            self.trace_counts.setdefault(key, 0)
-            return runner
+            return self._add(key, jax.jit(counting_step))
+
+    def loop_for(self, cfg, modes: dict[str, str], plan: DittoPlan, *, update,
+                 length: int, bucket: int | None = None) -> Callable:
+        """Jitted ``denoise_loop(dparams, mparams, state, latents, ts,
+        labels, update)`` (``dit_runner.make_loop_fn``) running ``length``
+        steps, keyed like :meth:`step_for` plus the sampler's ``update``
+        and ``length``. The state argument is donated: the loop's carry
+        takes its buffers, so the state is not held twice."""
+        plan, bucket, mode_sig = self._resolve(
+            "serve.CompiledRunnerCache.loop_for", modes, plan, bucket, UNSET, {})
+        fn = update.func
+        key = RunnerKey(cfg_signature(cfg), mode_sig, plan.cache_sig(), bucket,
+                        loop=(f"{fn.__module__}.{fn.__qualname__}", length))
+        with self._lock:
+            if key in self._steps:
+                self.hits += 1
+                return self._steps[key]
+            raw = dit_runner.make_loop_fn(cfg, modes, plan)
+
+            def denoise_loop(*args):  # the XLA module's name: not a "step"
+                self._count_trace(key)  # while tracing, as in step_for
+                return raw(*args)
+
+            return self._add(key, jax.jit(denoise_loop, donate_argnums=2))
+
+    def _add(self, key: RunnerKey, jitted) -> _Runner:
+        """Register a miss's jitted function (the lock is held)."""
+        self.misses += 1
+        runner = _Runner(jitted, self)
+        self._steps[key] = runner
+        self.trace_counts.setdefault(key, 0)
+        return runner
 
     # ---------------------------------------------------------------- warmup
     def warmup(self, cfg, modes: dict[str, str] | tuple, plans, buckets,
-               *, labels: bool = True, params=None) -> dict:
+               *, labels: bool = True, params=None, tail=None) -> dict:
         """AOT-compile the bucket ladder: one ``jax.jit(...).lower(...)
         .compile()`` per (segment plan, bucket), so the first REAL request
         of each key pays neither trace nor compile cost.
+
+        ``tail=(update, first)`` compiles, instead of the per-step
+        executable, the segment loop each segment runs over the compiled
+        tail: the sampler's ``update`` (``diffusion.loop_update``) over
+        the segment's steps from ``first`` (the first compiled step) on;
+        a segment wholly inside the eager prefix compiles nothing.
 
         ``plans`` is an iterable of :class:`DittoPlan`/``PlanSchedule``
         (a schedule warms every distinct segment sig); ``buckets`` the
@@ -333,10 +374,20 @@ class CompiledRunnerCache:
         # identity eval_shape: the struct tree with the runtime's treedef
         real_mparams = (None if params is None
                         else jax.eval_shape(lambda p: p, params))
+        if tail is not None:
+            update, first = tail
+            abstract_update = jax.eval_shape(lambda u: u, update)
         for plan in plans:
-            for _, _, seg in segment_view(plan):
+            for lo, hi, seg in segment_view(plan):
+                length = None if tail is None else hi - max(lo, first)
+                if length is not None and length <= 0:
+                    continue
                 for bucket in buckets:
-                    fn = self.step_for(cfg, modes, seg, bucket=bucket)
+                    if length is None:
+                        fn = self.step_for(cfg, modes, seg, bucket=bucket)
+                    else:
+                        fn = self.loop_for(cfg, modes, seg, bucket=bucket,
+                                           update=update, length=length)
                     if fn.aot_exe is not None:
                         continue
                     dparams, mparams, lat, t, lab = abstract_inputs(cfg, bucket)
@@ -346,6 +397,9 @@ class CompiledRunnerCache:
                         states[bucket] = abstract_state(cfg, bucket)
                     args = (dparams, mparams, states[bucket], lat, t,
                             lab if labels else None)
+                    if length is not None:
+                        ts = jax.ShapeDtypeStruct((length + 1,), jax.numpy.int32)
+                        args = args[:4] + (ts, args[5], abstract_update)
                     fn.aot_exe = fn.jitted.lower(*args).compile()
                     fn.aot_fp = _args_fingerprint(args)
                     compiled += 1

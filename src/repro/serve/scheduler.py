@@ -595,7 +595,10 @@ class ServeScheduler:
         first update is the same DDIM step) — to obtain the frozen
         per-layer modes, then lowers + compiles one executable per (plan
         segment sig, bucket) through
-        :meth:`CompiledRunnerCache.warmup`. First requests then skip both
+        :meth:`CompiledRunnerCache.warmup`: the segment loop over the
+        compiled tail (the steps after the probe's eager ones) where the
+        plan's sampler hands its steps to one (DDIM, watchdog off), else
+        the per-step executable. First requests then skip both
         the XLA trace and the XLA compile. Caveat: a request whose Defo
         decision differs from the probe's lands on a different RunnerKey
         and pays a cold compile (``aot_misses`` in ``stats()`` counts
@@ -626,14 +629,17 @@ class ServeScheduler:
         out = {"aot_compiled": 0, "traces": 0, "primed": 0}
         cfg = self.session.cfg
         for group_plans in by_probe.values():
-            modes = self._probe_modes(group_plans[0], labels=labels,
-                                      probe_seed=probe_seed)
+            modes, eager = self._probe_modes(group_plans[0], labels=labels,
+                                             probe_seed=probe_seed)
             for p in group_plans:
                 ladder = (_bucket_ladder(p.max_batch) if buckets is None
                           else buckets)
-                r = self.session.cache.warmup(self.session.cfg, modes, [p],
-                                              ladder, labels=labels,
-                                              params=self.session.params)
+                update = (diffusion.loop_update(p.sampler, self.session.sched)
+                          if p.compiled and not p.watchdog else None)
+                r = self.session.cache.warmup(
+                    self.session.cfg, modes, [p], ladder, labels=labels,
+                    params=self.session.params,
+                    tail=None if update is None else (update, eager))
                 out["aot_compiled"] += r["aot_compiled"]
                 out["traces"] += r["traces"]
                 for sess in self._sessions[1:]:
@@ -649,10 +655,11 @@ class ServeScheduler:
         out["wall_s"] = time.monotonic() - t0
         return out
 
-    def _probe_modes(self, plan, *, labels: bool, probe_seed: int) -> dict:
-        """Frozen per-layer modes from an eager calibration prefix: run
-        batch-1 seeded-noise forwards until the engine is ready for the
-        compiled pass (scales calibrated; Defo decided after step 2)."""
+    def _probe_modes(self, plan, *, labels: bool, probe_seed: int) -> tuple:
+        """``(modes, eager steps)``: the frozen per-layer modes from an
+        eager calibration prefix, and its length. Runs batch-1
+        seeded-noise forwards until the engine is ready for the compiled
+        pass (scales calibrated; Defo decided after step 2)."""
         cfg = self.session.cfg
         eng = DittoEngine(policy=plan.policy, collect_oracle=False)
         fn = make_denoise_fn(self.session.params, cfg, eng)
@@ -670,7 +677,7 @@ class ServeScheduler:
             t_vec = jnp.full((1,), t, jnp.int32)
             eps = fn(x, t_vec, lab)
             x = diffusion.ddim_step(self.session.sched, x, eps, t, t_prev)
-        return eng.compiled_modes()
+        return eng.compiled_modes(), eng.step_idx
 
     # ------------------------------------------------------------ internals
     def _demand(self, ticket: Ticket) -> None:

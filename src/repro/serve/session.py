@@ -45,7 +45,7 @@ from .bucketing import bucket_for
 from .cache import CompiledRunnerCache
 
 #: scalar counters a served chunk reports and ``ServeSession.stats()`` sums
-STEP_COUNTERS = ("host_reads", "eager_steps", "compiled_steps")
+STEP_COUNTERS = ("host_reads", "eager_steps", "compiled_steps", "looped_steps")
 #: a layer whose call-site name holds this runs on an MMDiT text stream
 TEXT_STREAM = ".txt."
 
@@ -61,7 +61,7 @@ class ChunkResult:
     wall_s: float
     traces_delta: int  # new XLA traces this chunk caused (0 = full cache hit)
     # the engine's counters (DittoEngine.counters): host_reads, eager_steps,
-    # compiled_steps, and tile_hist {diff layer: (zero, low, full)}
+    # compiled_steps, looped_steps, and tile_hist {diff layer: (zero, low, full)}
     counters: dict = dataclasses.field(default_factory=dict)
 
     @property

@@ -4,8 +4,9 @@
   * spans: a toy-width serve under ``jax.profiler.trace`` writes the
     ``serve.*``/``ditto.*``/``diffusion.*`` host spans, one per phase and
     step, each carrying the dispatch index;
-  * counters: ``host_reads`` (one per compiled step's packed statistics),
-    ``eager_steps``, ``compiled_steps`` and the per-layer ``tile_hist``
+  * counters: ``host_reads`` (one per segment loop's packed statistics),
+    ``eager_steps``, ``compiled_steps``, ``looped_steps`` and the per-layer
+    ``tile_hist``
     (accumulated on the device, read once per dispatch) with
     ``collect_stats`` on and off, summed into
     ``ServeSession.stats()`` and ``ServeScheduler.stats()``;
@@ -96,11 +97,14 @@ def test_host_reads_are_one_per_compiled_step_and_fall_with_stats_off(served):
     on, off = (served[s][2].counters["host_reads"] for s in (True, False))
     assert on > off
     records = served[True][2].records
-    compiled_steps = served[True][2].counters["compiled_steps"]
+    counters = served[True][2].counters
+    compiled_steps = counters["compiled_steps"]
     assert compiled_steps == len({r["step"] for r in records if r.get("compiled")})
-    # each compiled step's statistics are one packed fetch; the eager step
-    # adds its spatial oracle, three scalars per record that carries one
-    assert on - off == compiled_steps + 3 * sum(
+    # the compiled steps of a bare plan run as one segment loop, whose
+    # statistics are one packed fetch; the eager step adds its spatial
+    # oracle, three scalars per record that carries one
+    assert counters["looped_steps"] == compiled_steps
+    assert on - off == 1 + 3 * sum(
         1 for r in records if "cls_spatial" in r and not r.get("compiled"))
     # the jitted step returns its statistics as at most two arrays
     dparams, mparams, lat, t, labels = ta.abstract_inputs(CFG, 2)
@@ -173,17 +177,20 @@ def test_a_traced_serve_writes_one_span_per_phase_and_step(setup, tmp_path):
     assert counts["serve.dispatch"] == 1
     assert counts["serve.block"] == 1
     assert counts["ditto.requantize"] == 1
-    assert counts["diffusion.step"] == STEPS
+    # the eager steps are sampler steps of their own; the compiled ones run
+    # as one segment loop, whose statistics are recorded once
+    assert counts["diffusion.step"] == 2
     assert counts["ditto.eager_step"] == 2
-    assert counts["ditto.compiled_step"] == STEPS - 2
-    assert counts["ditto.record_step"] == STEPS - 2
+    assert counts["ditto.compiled_step"] == 0
+    assert counts["ditto.loop"] == 1
+    assert counts["ditto.record_step"] == 1
     assert counts["ditto.runner_build"] == 1
     # every span of the dispatch carries its index (the second serve: 1)
     assert {args.get("dispatch") for _, args in events} == {1}
     (args,) = [a for n, a in events if n == "serve.dispatch"]
     assert args["rows"] == 2 and str(args["buckets"]) == "2"
-    steps = sorted(a["step"] for n, a in events if n == "ditto.compiled_step")
-    assert steps == list(range(2, STEPS))
+    (loop,) = [a for n, a in events if n == "ditto.loop"]
+    assert (loop["step"], loop["steps"]) == (2, STEPS - 2)
 
 
 def test_step_ops_carry_the_block_scopes():

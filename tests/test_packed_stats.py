@@ -6,10 +6,12 @@ engine reads a step's statistics with one transfer. These tests serve one
 request twice at toy width: once as shipped, and once with the packing
 turned off and the records built by a per-scalar reader written here (one
 ``float``/``int`` read per scalar of the unpacked aux, as the engine read
-them before packing). Records must agree field for field and the served
-images bit for bit, across policies, batch 1 (no ``cls_spatial`` on the
-one-row ``mod`` layers) and batch 2, a forced watchdog re-anchor and a
-plan schedule's segment swap.
+them before packing; a segment loop's statistics, stacked on a step axis,
+are read step by step the same way). Records must agree field for field
+and the served images bit for bit, across policies, batch 1 (no
+``cls_spatial`` on the one-row ``mod`` layers) and batch 2, a forced
+watchdog re-anchor (the per-step path) and a plan schedule's segment swap
+(one segment loop per segment).
 """
 from typing import Any
 
@@ -42,7 +44,7 @@ def _request(b):
     return x, jnp.arange(b) % CFG.n_classes
 
 
-def per_scalar_record(self, aux, *, modes=None, reanchor=False):
+def per_scalar_record(self, aux, *, modes=None, reanchor=False, step=None):
     """Reference reader: one host read per scalar of the unpacked aux."""
     if self._compiled_base is None:
         base_by_layer: dict = {}
@@ -55,8 +57,9 @@ def per_scalar_record(self, aux, *, modes=None, reanchor=False):
     for name, a in aux.items():
         base = base_by_layer[name]
         meta = self.meta[name]
-        rec: dict[str, Any] = {"layer": name, "step": self.step_idx, "mode": modes[name],
-                               "kind": meta.kind, "macs": base["macs"], "compiled": True}
+        rec: dict[str, Any] = {"layer": name, "step": self.step_idx if step is None else step,
+                               "mode": modes[name], "kind": meta.kind, "macs": base["macs"],
+                               "compiled": True}
         if reanchor:
             rec["reanchor"] = True
         read = self.host_read
@@ -71,6 +74,15 @@ def per_scalar_record(self, aux, *, modes=None, reanchor=False):
             rec["tile_fracs"] = bops_mod.tile_fractions(hist)
             rec["bops_tile"] = bops_mod.bops_tile_mix(rec["macs"], hist)
         self.records.append(rec)
+
+
+def per_scalar_records(self, aux):
+    """Reference reader of a segment loop's unpacked aux, whose leaves are
+    stacked on a leading step axis: each step read per scalar."""
+    steps = jax.tree_util.tree_leaves(aux)[0].shape[0]
+    for i in range(steps):
+        per_scalar_record(self, jax.tree_util.tree_map(lambda v: v[i], aux),
+                          step=self.step_idx + i)
 
 
 def _serve(params, sched, plan, b):
@@ -97,6 +109,7 @@ def test_packed_records_equal_the_per_scalar_reader(setup, monkeypatch, label, p
     with monkeypatch.context() as m:
         m.setattr(compiled_mod, "pack_stats", lambda aux: aux)
         m.setattr(DittoEngine, "record_compiled_step", per_scalar_record)
+        m.setattr(DittoEngine, "record_compiled_steps", per_scalar_records)
         ref_image, ref_records, ref_eng = _serve(params, sched, plan, b)
     np.testing.assert_array_equal(image, ref_image)
     assert any(r.get("compiled") for r in records)

@@ -241,8 +241,10 @@ def test_behaviorally_distinct_schedules_split_groups(setup):
 @pytest.mark.slow
 def test_mixed_schedules_share_cache_but_not_traces(setup):
     """An int8→int4 schedule and a plain int8 plan coexist in one
-    scheduler/cache: two groups, and the schedule's extra segment is the
-    only extra runner — sig-equal segments share the bare plan's trace."""
+    scheduler/cache: two groups, one segment loop per (segment sig, loop
+    length). The schedule's int8 segment runs one compiled step after the
+    eager one, the bare plan two, so the two int8 loops are keyed apart
+    by their length."""
     params, sched = setup
     cache = CompiledRunnerCache()
     s = ServeScheduler(params, CFG, sched, PLAN, cache=cache)
@@ -252,8 +254,8 @@ def test_mixed_schedules_share_cache_but_not_traces(setup):
     s.flush()
     assert ta.done and t8.done
     keys = list(cache.trace_counts)
-    assert {k.low_bits for k in keys} == {4, 8}
-    assert len(cache) == 2  # int8 segment trace shared with the bare plan
+    assert {(k.low_bits, k.loop[1]) for k in keys} == {(8, 1), (4, 1), (8, 2)}
+    assert len(cache) == 3
     # both tickets bit-identical to solo serves under the matching plan
     sess = ServeSession(params, CFG, sched, PLAN, cache=CompiledRunnerCache())
     for t, seed, plan in ((ta, 50, SCHED_A), (t8, 51, PLAN)):
